@@ -9,8 +9,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.egrl import EGRL, EGRLConfig
 from repro.graphs.zoo import resnet50
+from repro.launch.compile_cache import enable_compile_cache
 from repro.memsim import tiers as T
 
+enable_compile_cache()
 graph = resnet50()
 print(f"workload: {graph.name}, {graph.n} nodes "
       f"(action space 3^{2 * graph.n} ~ 10^{int(2 * graph.n * 0.477)})")

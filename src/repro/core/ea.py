@@ -76,7 +76,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core import boltzmann as bz
@@ -421,7 +420,7 @@ def evolve_sharded(mesh, key, gnn_pop, fit_g, bz_pop, fit_b, gnn_logits, *,
                  crossover_prob=crossover_prob, mut_prob=mut_prob,
                  mut_frac=mut_frac, mut_std=mut_std, axis_name=POP_AXIS,
                  axis_size=n_shards)
-    sharded = shard_map(fn, mesh=mesh,
-                        in_specs=(rep, pop, pop, pop, pop, pop),
-                        out_specs=(pop, pop), check_rep=False)
+    sharded = jax.shard_map(fn, mesh=mesh,
+                            in_specs=(rep, pop, pop, pop, pop, pop),
+                            out_specs=(pop, pop), check_vma=False)
     return sharded(key, gnn_pop, fit_g, bz_pop, fit_b, gnn_logits)

@@ -29,8 +29,9 @@ policy), the stacked genome arrays carry a NamedSharding over a 1-D
 padded with masked rows (-inf fitness, PRNG draws sized by the real
 counts) so the real-row trajectory still matches the unpadded
 single-device run bit for bit.
-The GNN forward, rollout sampling and simulator evaluation then
-partition automatically under jit (per-genome work is independent),
+Rollout sampling and simulator evaluation then partition automatically
+under jit and the GNN forward runs shard by shard under ``shard_map``
+(per-genome work is independent),
 while the EA step runs ea.evolve_sharded — shard-local
 crossover/mutation/seeding with fitness all_gather + exact psum gathers
 for elites and parents — and PG migration writes through a jitted
@@ -384,11 +385,16 @@ class EGRL(_EvoPopulation):
         self._split_population()
         self._init_populations(self.feats.shape[1], graph.n, pop_shards)
 
-        # ---- vmapped population programs (auto-SPMD over sharded
-        # pops): bound module-level jits, so a second EGRL on the same
-        # graph geometry reuses the compiled executables
-        self._pop_gnn_logits = partial(
+        # ---- vmapped population programs: bound module-level jits, so
+        # a second EGRL on the same graph geometry reuses the compiled
+        # executables; a sharded population runs its forward shard by
+        # shard (``PopSharding.map_rows``), single rows on one device
+        self._row_gnn_logits = partial(
             _POP_LOGITS, self._template, self.feats, self.adj)
+        self._pop_gnn_logits = partial(
+            self.pop_sharding.map_rows(gnn.population_logits)
+            if self.pop_sharding.active else _POP_LOGITS,
+            self._template, self.feats, self.adj)
         self._pop_sample = _SAMPLE_ACTIONS
         self._pop_boltz = _bz_sample_pop
 
@@ -529,14 +535,14 @@ class EGRL(_EvoPopulation):
 
     # ----------------------------------------------------- deployment API
     def _prior_logits(self, vec):
-        return self._pop_gnn_logits(vec[None])[0]
+        return self._row_gnn_logits(vec[None])[0]
 
     def best_policy_logits(self):
         """Logits of the top-ranked policy in the population (deployment):
         the best GNN, else the SAC actor, else the best Boltzmann prior
         (Boltzmann-only "ea" ablation — crashed in the seed code)."""
         if self.n_g:
-            return self._pop_gnn_logits(self.gnn_pop[:1])[0]
+            return self._prior_logits(jnp.asarray(self.best_gnn_vec()))
         if self.mode != "ea":
             return self.learner.policy_logits()
         return bz.boltzmann_logits(bz.from_flat(self.bz_pop[0], self.g.n))
@@ -637,8 +643,9 @@ class ZooEGRL(_EvoPopulation):
         # ZooEGRL over the same bucket geometry (the placement service
         # builds one per miss batch on a canonical padding grid) reuses
         # the compiled executables; K buckets -> K cached entries per
-        # geometry (K small and static, so retracing is bounded)
-        self._pop_logits = [
+        # geometry (K small and static, so retracing is bounded).
+        # Single rows (warm-start priors) always run on one device.
+        self._bucket_logits = [
             partial(_POP_LOGITS_ZOO, self._template, b.feats, b.adj,
                     b.node_mask, b.n_nodes)
             for b in self.zoo.buckets]
@@ -686,6 +693,16 @@ class ZooEGRL(_EvoPopulation):
             self._wide_bucket = tuple(c * 2 >= top for c in costs)
         else:
             self._wide_bucket = (False,) * self.zoo.n_buckets
+        # a sharded population runs each bucket's forward shard by shard
+        # in its bucket's row layout (per-instance programs, like the
+        # sharded evolve)
+        self._pop_logits = self._bucket_logits
+        if self.pop_sharding.active:
+            self._pop_logits = [
+                partial(self.pop_sharding.map_rows(
+                    gnn.population_logits_zoo, wide=w), self._template,
+                    b.feats, b.adj, b.node_mask, b.n_nodes)
+                for w, b in zip(self._wide_bucket, self.zoo.buckets)]
 
         self.steps = 0
         self.best_reward = np.full(self.n_graphs, -np.inf)
@@ -855,7 +872,7 @@ class ZooEGRL(_EvoPopulation):
         # bucket-major (n_eff, 2, 3) grid, matching the bz genome layout
         return jnp.concatenate(
             [f(vec[None]).reshape(1, -1, 2, 3)
-             for f in self._pop_logits], axis=1)[0]
+             for f in self._bucket_logits], axis=1)[0]
 
     def best_gnn_vec(self) -> Optional[np.ndarray]:
         """Flat params of the best GNN after a generation (row 0); usable

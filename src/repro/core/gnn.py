@@ -22,8 +22,10 @@ training and inference share one dispatch:
 - ``"pallas"`` — the fused VMEM-resident kernel pair in
   repro.kernels.gat_mp (forward emits softmax residuals, backward
   recomputes attention block-wise; wrapped in ``custom_vjp`` by
-  ops.py).  Compiled on TPU; ``interpret`` mode elsewhere (slow — for
-  parity testing only, see tests/test_gat_backend.py).
+  ops.py).  Compiled for TPU (tests/test_tpu_compile.py compiles it
+  for a described v5e, chip_smoke.py runs it on the chip);
+  ``interpret`` mode elsewhere (slow — for parity testing only, see
+  tests/test_gat_backend.py).
 - ``"jnp"``  — dense (N, N, H) score materialization in plain jnp.
   Opt-in only (parity oracle / tiny graphs): no default path selects it.
 - ``"auto"`` — measurement-driven: a one-time per-(N, D, H, dtype)
@@ -226,8 +228,8 @@ def population_logits_zoo(template, feats, adj, node_mask, n_nodes,
                           pop_matrix, backend=None):
     """Zoo-wide stacked-population forward: (P, V) flat params ->
     (P, G, N_max, 2, 3).  Like ``population_logits``, the leading axis
-    is a pure vmap, so a ``("pop",)``-sharded ``pop_matrix`` partitions
-    shard-locally under auto-SPMD; the graph axis is replicated."""
+    is a pure vmap, so a ``("pop",)``-sharded ``pop_matrix`` runs shard
+    by shard; the graph axis is replicated."""
     return jax.vmap(lambda vec: gnn_forward_zoo(
         unflatten_params(template, vec), feats, adj, node_mask, n_nodes,
         backend))(pop_matrix)
@@ -248,7 +250,7 @@ def population_logits_bucketed(template, buckets, pop_matrix, backend=None):
     """Stacked-population forward per bucket: (P, V) flat params ->
     tuple of (P, G_k, N_max_k, 2, 3).  Each per-bucket call is the same
     pure vmap as ``population_logits_zoo``, so a ("pop",)-sharded
-    ``pop_matrix`` still partitions shard-locally under auto-SPMD —
+    ``pop_matrix`` still runs shard by shard —
     bucketing composes with population sharding bucket by bucket."""
     return tuple(population_logits_zoo(template, b.feats, b.adj, b.node_mask,
                                        b.n_nodes, pop_matrix, backend)
@@ -291,12 +293,12 @@ def population_logits(template, feats, adj, pop_matrix,
                       backend: Optional[str] = None):
     """Stacked-population forward: (P, V) flat params -> (P, N, 2, 3).
 
-    A pure vmap over the leading axis, so when ``pop_matrix`` carries a
-    ``NamedSharding`` over a ``("pop",)`` mesh axis the jitted call
-    partitions automatically (auto-SPMD): each device runs the forward
-    only for the genome rows it owns — no host round-trips and no
-    collectives, since per-genome forwards are independent.  ``feats`` /
-    ``adj`` / the ``template`` pytree are replicated.
+    A pure vmap over the leading axis, so a ``("pop",)``-sharded
+    ``pop_matrix`` runs shard by shard (``PopSharding.map_rows``): each
+    device runs the forward only for the genome rows it owns — no host
+    round-trips and no collectives, since per-genome forwards are
+    independent.  ``feats`` / ``adj`` / the ``template`` pytree are
+    replicated.
     """
     return jax.vmap(lambda vec: gnn_forward(
         unflatten_params(template, vec), feats, adj, backend))(pop_matrix)

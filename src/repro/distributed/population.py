@@ -7,10 +7,11 @@ The EGRL inner loop stores its population as stacked device arrays
 chip or are row-sharded across a 1-D device mesh.  The actual sharded
 EA step is ``repro.core.ea.evolve_sharded`` (bit-identical to the
 single-device ``evolve`` on real rows for any valid shard count);
-population evaluation and the population GNN forward partition
-automatically under jit once their inputs carry a ``NamedSharding``
-(auto-SPMD — every per-genome computation is independent, so no
-collectives are needed outside the EA step).
+population evaluation partitions automatically under jit once its
+inputs carry a ``NamedSharding`` (auto-SPMD), and the population GNN
+forward runs shard by shard through ``PopSharding.map_rows`` — every
+per-genome computation is independent, so no collectives are needed
+outside the EA step.
 
 Padded slots (PR 3): a shard count that does not divide a
 sub-population no longer forces the single-device fallback.  The
@@ -112,6 +113,23 @@ class PopSharding:
     def put_wide(self, x):
         """Place a stacked (P, ...) array row-split over all devices."""
         return jax.device_put(x, self.wide_sharding) if self.active else x
+
+    def map_rows(self, fn, wide: bool = False):
+        """Jitted ``fn(*replicated, rows)`` run shard by shard over the
+        rows of its last argument (``sharding``, or ``wide_sharding``
+        when ``wide``), every other argument replicated.  The per-row
+        work is independent, and a Pallas kernel inside cannot be
+        partitioned by the compiler, so the split is an explicit
+        ``shard_map`` rather than auto-SPMD."""
+        assert self.mesh is not None
+        spec = (self.wide_sharding if wide else self.sharding).spec
+
+        def run(*args):
+            in_specs = (PartitionSpec(),) * (len(args) - 1) + (spec,)
+            return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=spec, check_vma=False)(*args)
+
+        return jax.jit(run)
 
     def padded(self, n_g: int, n_b: int) -> Tuple[int, int]:
         """Row counts the population arrays must be allocated with."""

@@ -14,10 +14,18 @@ recomputes each block's attention weights in VMEM from ``(m, l)`` and
 accumulates grads w.r.t. ``z`` / ``e_src`` / ``e_dst`` (``adj`` is
 non-differentiable).  The ``dz`` / ``de_dst`` outputs use a constant
 block index, so the sequential TPU grid revisits one VMEM buffer and
-accumulates across destination blocks (same pattern as the
-``kernels/flash_attention`` scratch accumulator).
+accumulates across destination blocks.
 
-Workload graphs are <= ~1k nodes, so the full (N, H, hd) node-feature
+TPU layout: every score tile is a 2-D ``(bn, N)`` array with N on the
+128-wide lane axis, one per head in a static loop — a ``(bn, N, H)``
+tile would pad H=4 to 128 lanes.  No value is reshaped across the lane
+axis: each head's aggregate is one ``(bn, N) x (N, D)`` matmul over all
+D columns of z, of which only the head's own ``hd`` columns are kept
+(a lane-masked select), so the MXU work equals an ``hd``-wide matmul
+padded to the MXU width.  ``e_dst`` arrives head-major ``(H, N)`` so
+each head's row is a sublane slice, and ``de_dst`` leaves the same way.
+
+Workload graphs are <= ~1k nodes, so the full (N, D) node-feature
 tensor (~0.5 MB at N=1024, D=128) sits in VMEM; the grid tiles only the
 destination nodes.
 """
@@ -32,33 +40,38 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def _fwd_kernel(z_ref, esrc_ref, edst_ref, adj_ref, o_ref, m_ref, l_ref, *,
+def _head_lanes(bn: int, d: int, heads: int, h: int):
+    """(bn, D) mask of the lanes that belong to head ``h``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, d), 1)
+    return lane // (d // heads) == h
+
+
+def _scores(e_src, e_dst_t, adj, h: int):
+    """Head ``h``'s pre-activation and masked leaky-relu scores, (bn, N)."""
+    pre = e_src[:, h:h + 1] + e_dst_t[h:h + 1, :]
+    s = jnp.where(pre >= 0, pre, 0.2 * pre)
+    return pre, jnp.where(adj > 0, s, NEG_INF)
+
+
+def _fwd_kernel(z_ref, esrc_ref, edstt_ref, adj_ref, o_ref, m_ref, l_ref, *,
                 heads: int):
-    z = z_ref[...]                        # (N, H*hd) all nodes
-    e_dst = edst_ref[...]                 # (N, H)
+    z = z_ref[...]                        # (N, D) all nodes
+    e_dst_t = edstt_ref[...]              # (H, N)
     e_src = esrc_ref[...]                 # (bn, H) this block's nodes
     adj = adj_ref[...]                    # (bn, N)
-    N, D = z.shape
-    hd = D // heads
-    bn = e_src.shape[0]
-
-    s = e_src[:, None, :] + e_dst[None, :, :]           # (bn, N, H)
-    s = jnp.where(s >= 0, s, 0.2 * s)                   # leaky_relu
-    s = jnp.where(adj[:, :, None] > 0, s, NEG_INF)
-    m = s.max(axis=1)                                   # (bn, H)
-    p = jnp.exp(s - m[:, None, :])
-    l = p.sum(axis=1)                                   # (bn, H)
-    p = p / jnp.maximum(l, 1e-30)[:, None, :]           # (bn, N, H)
-
-    zh = z.reshape(N, heads, hd)
-    # batch the head dim through dot_general: (H, bn, N) x (H, N, hd)
-    out = jax.lax.dot_general(
-        p.transpose(2, 0, 1), zh.transpose(1, 0, 2),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)             # (H, bn, hd)
-    o_ref[...] = out.transpose(1, 0, 2).reshape(bn, D).astype(o_ref.dtype)
-    m_ref[...] = m.astype(m_ref.dtype)
-    l_ref[...] = l.astype(l_ref.dtype)
+    bn, D = o_ref.shape
+    out = jnp.zeros((bn, D), jnp.float32)
+    for h in range(heads):
+        _, s = _scores(e_src, e_dst_t, adj, h)
+        m = s.max(axis=1, keepdims=True)                # (bn, 1)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=1, keepdims=True)                # (bn, 1)
+        p = p / jnp.maximum(l, 1e-30)
+        agg = jnp.dot(p, z, preferred_element_type=jnp.float32)  # (bn, D)
+        out = jnp.where(_head_lanes(bn, D, heads, h), agg, out)
+        m_ref[:, h:h + 1] = m.astype(m_ref.dtype)
+        l_ref[:, h:h + 1] = l.astype(l_ref.dtype)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def gat_mp_pallas(z, e_src, e_dst, adj, *, heads: int, block: int = 128,
@@ -78,7 +91,7 @@ def gat_mp_pallas(z, e_src, e_dst, adj, *, heads: int, block: int = 128,
         in_specs=[
             pl.BlockSpec((N, D), lambda i: (0, 0)),
             pl.BlockSpec((bn, heads), lambda i: (i, 0)),
-            pl.BlockSpec((N, heads), lambda i: (0, 0)),
+            pl.BlockSpec((heads, N), lambda i: (0, 0)),
             pl.BlockSpec((bn, N), lambda i: (i, 0)),
         ],
         out_specs=[
@@ -92,55 +105,49 @@ def gat_mp_pallas(z, e_src, e_dst, adj, *, heads: int, block: int = 128,
             jax.ShapeDtypeStruct((N, heads), jnp.float32),
         ],
         interpret=interpret,
-    )(z, e_src, e_dst, adj)
+    )(z, e_src, e_dst.T, adj)
 
 
-def _bwd_kernel(z_ref, esrc_ref, edst_ref, adj_ref, m_ref, l_ref, o_ref,
-                g_ref, dz_ref, desrc_ref, dedst_ref, *, heads: int):
+def _bwd_kernel(z_ref, esrc_ref, edstt_ref, adj_ref, m_ref, l_ref, o_ref,
+                g_ref, dz_ref, desrc_ref, dedstt_ref, *, heads: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         # dz / de_dst blocks revisit the same VMEM buffer every grid step
         dz_ref[...] = jnp.zeros_like(dz_ref)
-        dedst_ref[...] = jnp.zeros_like(dedst_ref)
+        dedstt_ref[...] = jnp.zeros_like(dedstt_ref)
 
     z = z_ref[...]                        # (N, D)
-    N, D = z.shape
-    hd = D // heads
     e_src = esrc_ref[...]                 # (bn, H)
-    e_dst = edst_ref[...]                 # (N, H)
+    e_dst_t = edstt_ref[...]              # (H, N)
     adj = adj_ref[...]                    # (bn, N)
     m = m_ref[...]                        # (bn, H)
     l = jnp.maximum(l_ref[...], 1e-30)
-    bn = e_src.shape[0]
-    g = g_ref[...].reshape(bn, heads, hd).astype(jnp.float32)
-    o = o_ref[...].reshape(bn, heads, hd).astype(jnp.float32)
-
-    pre = e_src[:, None, :] + e_dst[None, :, :]         # (bn, N, H)
-    s = jnp.where(pre >= 0, pre, 0.2 * pre)
-    s = jnp.where(adj[:, :, None] > 0, s, NEG_INF)
-    p = jnp.exp(s - m[:, None, :]) / l[:, None, :]      # alpha (bn, N, H)
-
-    # dz_j += sum_i alpha_ij g_i : (H, N, bn) x (H, bn, hd) -> (H, N, hd)
-    dz = jax.lax.dot_general(
-        p.transpose(2, 1, 0), g.transpose(1, 0, 2),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    dz_ref[...] += dz.transpose(1, 0, 2).reshape(N, D).astype(dz_ref.dtype)
-
-    zh = z.reshape(N, heads, hd)
-    # dalpha_ij = g_i . zh_j : (H, bn, hd) x (H, N, hd) -> (H, bn, N)
-    dalpha = jax.lax.dot_general(
-        g.transpose(1, 0, 2), zh.transpose(1, 0, 2),
-        (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).transpose(1, 2, 0)  # (bn, N, H)
-    drow = (g * o).sum(-1)                              # (bn, H)
-    ds = p * (dalpha - drow[:, None, :])
-    dpre = jnp.where(pre >= 0, ds, 0.2 * ds)
-    dpre = jnp.where(adj[:, :, None] > 0, dpre, 0.0)
-    desrc_ref[...] = dpre.sum(axis=1).astype(desrc_ref.dtype)
-    dedst_ref[...] += dpre.sum(axis=0).astype(dedst_ref.dtype)
+    g = g_ref[...].astype(jnp.float32)    # (bn, D)
+    go = g * o_ref[...].astype(jnp.float32)
+    bn, D = g.shape
+    for h in range(heads):
+        pre, s = _scores(e_src, e_dst_t, adj, h)
+        p = jnp.exp(s - m[:, h:h + 1]) / l[:, h:h + 1]  # alpha (bn, N)
+        lanes = _head_lanes(bn, D, heads, h)
+        gh = jnp.where(lanes, g, 0.0)                   # g_i, head h only
+        # dz_j += sum_i alpha_ij g_i : (bn, N)^T x (bn, D) -> (N, D)
+        dz_ref[...] += jax.lax.dot_general(
+            p, gh, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(dz_ref.dtype)
+        # dalpha_ij = g_i . z_j over head h's lanes : (bn, D) x (N, D)^T
+        dalpha = jax.lax.dot_general(
+            gh, z, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # (bn, N)
+        drow = jnp.where(lanes, go, 0.0).sum(axis=1, keepdims=True)
+        ds = p * (dalpha - drow)
+        dpre = jnp.where(pre >= 0, ds, 0.2 * ds)
+        dpre = jnp.where(adj > 0, dpre, 0.0)
+        desrc_ref[:, h:h + 1] = dpre.sum(axis=1, keepdims=True).astype(
+            desrc_ref.dtype)
+        dedstt_ref[h:h + 1, :] += dpre.sum(axis=0, keepdims=True).astype(
+            dedstt_ref.dtype)
 
 
 def gat_mp_bwd_pallas(z, e_src, e_dst, adj, m, l, o, g, *, heads: int,
@@ -152,13 +159,13 @@ def gat_mp_bwd_pallas(z, e_src, e_dst, adj, m, l, o, g, *, heads: int,
     bn = min(block, N)
     assert N % bn == 0
     kern = functools.partial(_bwd_kernel, heads=heads)
-    return pl.pallas_call(
+    dz, de_src, de_dst_t = pl.pallas_call(
         kern,
         grid=(N // bn,),
         in_specs=[
             pl.BlockSpec((N, D), lambda i: (0, 0)),
             pl.BlockSpec((bn, heads), lambda i: (i, 0)),
-            pl.BlockSpec((N, heads), lambda i: (0, 0)),
+            pl.BlockSpec((heads, N), lambda i: (0, 0)),
             pl.BlockSpec((bn, N), lambda i: (i, 0)),
             pl.BlockSpec((bn, heads), lambda i: (i, 0)),
             pl.BlockSpec((bn, heads), lambda i: (i, 0)),
@@ -168,12 +175,13 @@ def gat_mp_bwd_pallas(z, e_src, e_dst, adj, m, l, o, g, *, heads: int,
         out_specs=[
             pl.BlockSpec((N, D), lambda i: (0, 0)),
             pl.BlockSpec((bn, heads), lambda i: (i, 0)),
-            pl.BlockSpec((N, heads), lambda i: (0, 0)),
+            pl.BlockSpec((heads, N), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N, D), z.dtype),
             jax.ShapeDtypeStruct((N, heads), e_src.dtype),
-            jax.ShapeDtypeStruct((N, heads), e_dst.dtype),
+            jax.ShapeDtypeStruct((heads, N), e_dst.dtype),
         ],
         interpret=interpret,
-    )(z, e_src, e_dst, adj, m, l, o, g)
+    )(z, e_src, e_dst.T, adj, m, l, o, g)
+    return dz, de_src, de_dst_t.T

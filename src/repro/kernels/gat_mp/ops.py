@@ -6,7 +6,8 @@ e_dst, ``adj`` non-diff):
 
 - ``gat_mp`` — the Pallas kernel pair in ``gat_mp.py`` (forward emits
   per-row softmax residuals; backward recomputes attention block-wise in
-  VMEM).  Compiled on TPU; interpret mode elsewhere (parity only).
+  VMEM).  Compiled for TPU (tests/test_tpu_compile.py); interpret mode
+  elsewhere (parity only).
 - ``gat_mp_chunked`` — the pure-XLA online-softmax scan in
   ``chunked.py`` (recompute-in-backward), the training path CPU/GPU
   actually use.

@@ -4,21 +4,27 @@ before first jax init)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """Mesh with every axis ``AxisType.Auto``: the sharding of each
+    intermediate is left to the compiler, which population evaluation
+    (auto-SPMD over "pop") and ``with_sharding_constraint`` rely on.
+    Every mesh in the repo is built here (tests use small shapes like
+    (2, 4))."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests use small shapes like (2, 4))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def _check_devices(needed: int, what: str) -> None:
-    """Fail loud BEFORE jax.make_mesh when a requested mesh wants more
+    """Fail loud BEFORE building the mesh when a requested mesh wants more
     devices than exist — otherwise the request surfaces much later as an
     opaque XLA sharding error deep inside a jitted call."""
     n_dev = len(jax.devices())
@@ -40,7 +46,7 @@ def make_pop_mesh(n_shards: int | None = None):
     n = n_shards or len(jax.devices())
     _check_devices(n, f"REPRO_POP_SHARDS={n_shards}" if n_shards
                    else "make_pop_mesh()")
-    return jax.make_mesh((n,), ("pop",))
+    return make_mesh((n,), ("pop",))
 
 
 def make_pop_model_mesh(pop_shards: int, model_shards: int):
@@ -57,4 +63,4 @@ def make_pop_model_mesh(pop_shards: int, model_shards: int):
     needed = pop_shards * model_shards
     _check_devices(needed, f"REPRO_POP_SHARDS={pop_shards} x "
                            f"REPRO_MODEL_SHARDS={model_shards}")
-    return jax.make_mesh((pop_shards, model_shards), ("pop", "model"))
+    return make_mesh((pop_shards, model_shards), ("pop", "model"))

@@ -18,6 +18,7 @@ from repro.configs.registry import ARCH_IDS
 from repro.core.egrl import EGRL, EGRLConfig
 from repro.graphs.extract import extract_for
 from repro.graphs.zoo import PAPER_WORKLOADS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.memsim import tiers as T
 from repro.memsim.compiler import compiler_reference
 from repro.memsim.simulator import build_sim_graph, evaluate
@@ -77,6 +78,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="experiments/plans")
     args = ap.parse_args()
+    enable_compile_cache()
 
     plan, _ = optimize(args.arch, args.shape, args.steps, args.mode, args.seed)
     os.makedirs(args.out, exist_ok=True)

@@ -25,6 +25,7 @@ import numpy as np
 from repro import obs
 from repro.configs.base import SHAPES
 from repro.configs.registry import ARCH_IDS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.log import get_logger
 from repro.serving.placement_service import (PlacementRequest,
                                              PlacementResult,
@@ -155,6 +156,7 @@ def main():
     ap.add_argument("--out", default=None,
                     help="write the summary JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     reqs = synthetic_stream(args.requests, seed=args.seed,
                             archs=args.archs, shapes=args.shapes)

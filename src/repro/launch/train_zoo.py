@@ -22,6 +22,7 @@ import os
 
 from repro.core.egrl import EGRLConfig, ZooEGRL, evaluate_gnn_zoo
 from repro.graphs.zoo import WORKLOADS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.log import get_logger, set_quiet
 
 _log = get_logger("train_zoo")
@@ -72,6 +73,7 @@ def main():
                     help="suppress per-generation progress lines")
     args = ap.parse_args()
     set_quiet(args.quiet)
+    enable_compile_cache()
 
     report, _ = train_zoo(args.train, args.holdout, args.steps, args.mode,
                           args.agg, args.seed, args.buckets)

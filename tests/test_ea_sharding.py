@@ -74,6 +74,7 @@ import jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import ea, boltzmann as bz
+from repro.launch.mesh import make_mesh
 
 n_g, n_b, n, v = 12, 4, 8, 40
 kw = dict(n_nodes=n, e_g=3, e_b=1, tournament_k=3, crossover_prob=0.7,
@@ -88,7 +89,7 @@ key = jax.random.PRNGKey(5)
 ref_g, ref_b = jax.jit(partial(ea.evolve, **kw))(
     key, g_pop, fit_g, b_pop, fit_b, logits)
 for s in (1, 2, 4):
-    mesh = jax.make_mesh((s,), ("pop",))
+    mesh = make_mesh((s,), ("pop",))
     sh = NamedSharding(mesh, P("pop"))
     args = [jax.device_put(x, sh) for x in (g_pop, fit_g, b_pop, fit_b, logits)]
     out_g, out_b = jax.jit(partial(ea.evolve_sharded, mesh, **kw))(key, *args)
@@ -98,7 +99,7 @@ for s in (1, 2, 4):
     order = jnp.argsort(-fit_g)
     assert (out_g[:3] == g_pop[order[:3]]).all()
 # non-dividing mesh fails loudly instead of desynchronizing slots
-mesh3 = jax.make_mesh((3,), ("pop",))
+mesh3 = make_mesh((3,), ("pop",))
 try:
     ea.evolve_sharded(mesh3, key, g_pop, fit_g, b_pop, fit_b, logits, **kw)
 except ValueError as e:

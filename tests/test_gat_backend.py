@@ -8,6 +8,7 @@ Pallas runs in interpret mode on CPU (parity only)."""
 import numpy as np
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
 
@@ -223,9 +224,9 @@ def _all_shapes(jaxpr, acc):
 
 
 def _sub_jaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jax.extend.core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jax.extend.core.Jaxpr):
         yield val
     elif isinstance(val, (tuple, list)):
         for v in val:
