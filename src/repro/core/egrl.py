@@ -408,11 +408,10 @@ class EGRL(_EvoPopulation):
         # span timing note: jax dispatch is async, so the rollout /
         # evolve child spans measure DISPATCH (+ compile on a first
         # call, split out as jit_compile by _compile_tracked); the
-        # device wait lands in host_sync — the generation loop's one
-        # host sync, unchanged by instrumentation.
-        with obs.profile_block(), \
-                obs.span("generation", driver="egrl",
-                         mode=self.mode) as sp:
+        # device waits land in the device_read spans (host_sync's
+        # result reads, sac.read's loss reads), which instrumentation
+        # neither adds nor moves.
+        with obs.span("generation", driver="egrl", mode=self.mode) as sp:
             return self._generation(sp)
 
     def _generation(self, sp) -> Dict:
@@ -466,11 +465,11 @@ class EGRL(_EvoPopulation):
                     logits_g if logits_g is not None
                     else jnp.zeros((0, self.g.n, 2, 3)))
 
-        # ---- the ONE host sync per generation: buffer + logging
-        # (padding rows are sliced away — they never hit the buffer,
-        # the step count or the best-mapping tracking)
+        # ---- host sync: one blocking read per result array, for the
+        # buffer + logging (padding rows are sliced away — they never
+        # hit the buffer, the step count or the best-mapping tracking)
         def np_real(name, x):
-            a = np.asarray(x)
+            a = obs.device_read(np.asarray, x)
             return a[:real[name]] if name in real else a
 
         with obs.span("host_sync"):
@@ -481,12 +480,14 @@ class EGRL(_EvoPopulation):
                 [np_real(n, m) for n, m in parts.items()])
             valid = np.concatenate(
                 [np_real(n, results[n]["valid"]) for n in parts])
-        self.steps += len(maps_np)
-        self.buffer.add_batch(maps_np, rewards)
-        gen_best = int(np.argmax(rewards))
-        if rewards[gen_best] > self.best_reward:
-            self.best_reward = float(rewards[gen_best])
-            self.best_mapping = maps_np[gen_best].copy()
+        with obs.span("replay.insert"):
+            self.buffer.add_batch(maps_np, rewards)
+        with obs.span("bookkeeping"):
+            self.steps += len(maps_np)
+            gen_best = int(np.argmax(rewards))
+            if rewards[gen_best] > self.best_reward:
+                self.best_reward = float(rewards[gen_best])
+                self.best_mapping = maps_np[gen_best].copy()
 
         # ---- PG updates: one gradient step per env step this generation
         info = {}
@@ -499,8 +500,10 @@ class EGRL(_EvoPopulation):
             # slot is an elite (n_g == e_g) skip, preserving elitism.
             if self.mode == "egrl" and n_g > self.e_g:
                 obs.counter("egrl.migrations").inc()
-                self.gnn_pop = self._migrate(
-                    self.gnn_pop, gnn.flatten_params(self.learner.actor))
+                with obs.span("migrate"):
+                    self.gnn_pop = self._migrate(
+                        self.gnn_pop,
+                        gnn.flatten_params(self.learner.actor))
         obs.gauge("egrl.replay_occupancy").set(len(self.buffer))
 
         rec = {
@@ -712,9 +715,7 @@ class ZooEGRL(_EvoPopulation):
 
     def generation(self) -> Dict:
         # same dispatch-vs-sync span semantics as EGRL.generation
-        with obs.profile_block(), \
-                obs.span("generation", driver="zoo",
-                         mode=self.mode) as sp:
+        with obs.span("generation", driver="zoo", mode=self.mode) as sp:
             return self._generation(sp)
 
     def _generation(self, sp) -> Dict:
@@ -774,8 +775,10 @@ class ZooEGRL(_EvoPopulation):
 
         # ---- EA step on the aggregate fitness, still on device
         empty = jnp.zeros((0,), jnp.float32)
-        fit = {name: aggregate_rewards(results[name]["reward"], self.agg)
-               for name in parts}
+        with obs.span("fitness"):
+            fit = {name: aggregate_rewards(results[name]["reward"],
+                                           self.agg)
+                   for name in parts}
         if n_g or n_b:
             with obs.span("evolve"):
                 self.gnn_pop, self.bz_pop = self._evolve(
@@ -790,9 +793,10 @@ class ZooEGRL(_EvoPopulation):
                     if logits_g is not None
                     else jnp.zeros((0, self.n_eff, 2, 3)))
 
-        # ---- the ONE host sync per generation
+        # ---- host sync: one blocking read per result array (3 per
+        # part, plus one per part and bucket for the rollout rows)
         def np_real(name, x):
-            a = np.asarray(x)
+            a = obs.device_read(np.asarray, x)
             return a[:real[name]] if name in real else a
 
         with obs.span("host_sync"):
@@ -805,20 +809,22 @@ class ZooEGRL(_EvoPopulation):
             # per-bucket host copies of the rollout rows (real rows only)
             maps_np = {name: [np_real(name, m) for m in bucket_maps]
                        for name, bucket_maps in parts.items()}
-        self.steps += rewards.size          # one env step per (genome, graph)
-        # per-graph action stacks in the SAME part order as `rewards`
-        # rows (g, b, pg) — graph gi's rows live at its (bucket, slot)
-        acts_by_graph = [
-            np.concatenate([maps_np[name][zoo.graph_bucket[gi]]
-                            [:, zoo.graph_slot[gi]] for name in parts])
-            for gi in range(self.n_graphs)]
-        for gi in range(self.n_graphs):
-            b = int(np.argmax(rewards[:, gi]))
-            if rewards[b, gi] > self.best_reward[gi]:
-                self.best_reward[gi] = float(rewards[b, gi])
-                self.best_mapping[gi] = acts_by_graph[gi][
-                    b, :self.n_nodes[gi]].copy()
-        self.best_fitness = max(self.best_fitness, float(fitness.max()))
+        with obs.span("bookkeeping"):
+            self.steps += rewards.size      # one env step per (genome, graph)
+            # per-graph action stacks in the SAME part order as `rewards`
+            # rows (g, b, pg) — graph gi's rows live at its (bucket, slot)
+            acts_by_graph = [
+                np.concatenate([maps_np[name][zoo.graph_bucket[gi]]
+                                [:, zoo.graph_slot[gi]] for name in parts])
+                for gi in range(self.n_graphs)]
+            for gi in range(self.n_graphs):
+                b = int(np.argmax(rewards[:, gi]))
+                if rewards[b, gi] > self.best_reward[gi]:
+                    self.best_reward[gi] = float(rewards[b, gi])
+                    self.best_mapping[gi] = acts_by_graph[gi][
+                        b, :self.n_nodes[gi]].copy()
+            self.best_fitness = max(self.best_fitness,
+                                    float(fitness.max()))
 
         # ---- PG member: bank insert, one batched zoo-wide gradient
         # step per rollout row (the update scan consumes a per-bucket
@@ -827,13 +833,17 @@ class ZooEGRL(_EvoPopulation):
         # migration into the last real GNN slot
         info = {}
         if self.mode != "ea":
-            for gi in range(self.n_graphs):
-                self.bank.add_graph(gi, acts_by_graph[gi], rewards[:, gi])
+            with obs.span("replay.insert"):
+                for gi in range(self.n_graphs):
+                    self.bank.add_graph(gi, acts_by_graph[gi],
+                                        rewards[:, gi])
             info = self.learner.update(self.bank, len(rewards))
             if self.mode == "egrl" and n_g > self.e_g:
                 obs.counter("egrl.migrations").inc()
-                self.gnn_pop = self._migrate(
-                    self.gnn_pop, gnn.flatten_params(self.learner.actor))
+                with obs.span("migrate"):
+                    self.gnn_pop = self._migrate(
+                        self.gnn_pop,
+                        gnn.flatten_params(self.learner.actor))
         if self.bank is not None:
             obs.gauge("egrl.replay_occupancy").set(len(self.bank))
 

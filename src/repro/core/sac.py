@@ -218,24 +218,31 @@ class SACLearner:
         cfg = self.cfg
         if len(buffer) < cfg.batch or steps <= 0:
             return {}
-        # the update already ends on host floats (an existing sync), so
-        # the span adds timing without any new device wait
+        # the update already ends on host floats (existing syncs, the
+        # device_read spans of sac.read), so the spans add timing
+        # without any new device wait
         with obs.span("sac_update", learner="sac", steps=steps,
                       batch=cfg.batch) as sp:
-            pairs = [buffer.sample(cfg.batch) for _ in range(steps)]
-            acts = np.stack([p[0] for p in pairs])
-            rews = np.stack([p[1] for p in pairs])
-            self.key, k = jax.random.split(self.key)
-            noise = jnp.clip(
-                cfg.action_noise * jax.random.normal(
-                    k, (steps, cfg.batch) + acts.shape[2:] + (3,)),
-                -cfg.noise_clip, cfg.noise_clip)
-            (self.actor, self.critic, self.opt_a, self.opt_c,
-             cl, al, en) = self._update_scan(
-                self.actor, self.critic, self.opt_a, self.opt_c,
-                jnp.asarray(acts), jnp.asarray(rews), noise)
-            out = {"critic_loss": float(cl), "actor_loss": float(al),
-                   "entropy": float(en)}
+            with obs.span("replay.sample"):
+                pairs = [buffer.sample(cfg.batch) for _ in range(steps)]
+                acts = np.stack([p[0] for p in pairs])
+                rews = np.stack([p[1] for p in pairs])
+            with obs.span("sac.upload"):
+                self.key, k = jax.random.split(self.key)
+                noise = jnp.clip(
+                    cfg.action_noise * jax.random.normal(
+                        k, (steps, cfg.batch) + acts.shape[2:] + (3,)),
+                    -cfg.noise_clip, cfg.noise_clip)
+                acts, rews = jnp.asarray(acts), jnp.asarray(rews)
+            with obs.span("sac.scan"):
+                (self.actor, self.critic, self.opt_a, self.opt_c,
+                 cl, al, en) = self._update_scan(
+                    self.actor, self.critic, self.opt_a, self.opt_c,
+                    acts, rews, noise)
+            with obs.span("sac.read"):
+                out = {"critic_loss": obs.device_read(float, cl),
+                       "actor_loss": obs.device_read(float, al),
+                       "entropy": obs.device_read(float, en)}
             sp.set(**out)
             return out
 
@@ -357,25 +364,31 @@ class ZooSAC:
         cfg = self.cfg
         if len(bank) < cfg.batch or steps <= 0:
             return {}
-        # same as SACLearner.update: float() below is the existing host
-        # sync, so the span adds no device wait
+        # same spans as SACLearner.update; the loss reads of sac.read
+        # are the existing host syncs, so the spans add no device wait
         with obs.span("sac_update", learner="zoo_sac", steps=steps,
                       batch=cfg.batch) as sp:
-            acts, rews = [], []
-            for ids in self._bucket_ids:
-                a, r = bank.sample_bucket(ids, cfg.batch, steps)
-                acts.append(jnp.asarray(a))
-                rews.append(jnp.asarray(r))
-            self.key, k = jax.random.split(self.key)
-            noise = tuple(jnp.clip(
-                cfg.action_noise * jax.random.normal(kk, a.shape + (3,)),
-                -cfg.noise_clip, cfg.noise_clip)
-                for kk, a in zip(bucket_keys(k, self.zoo.n_buckets), acts))
-            (self.actor, self.critic, self.opt_a, self.opt_c,
-             cl, al, en) = self._update_scan(
-                self.actor, self.critic, self.opt_a, self.opt_c,
-                tuple(acts), tuple(rews), noise)
-            out = {"critic_loss": float(cl), "actor_loss": float(al),
-                   "entropy": float(en)}
+            with obs.span("replay.sample"):
+                batches = [bank.sample_bucket(ids, cfg.batch, steps)
+                           for ids in self._bucket_ids]
+            with obs.span("sac.upload"):
+                acts = tuple(jnp.asarray(a) for a, _ in batches)
+                rews = tuple(jnp.asarray(r) for _, r in batches)
+                self.key, k = jax.random.split(self.key)
+                noise = tuple(jnp.clip(
+                    cfg.action_noise * jax.random.normal(
+                        kk, a.shape + (3,)),
+                    -cfg.noise_clip, cfg.noise_clip)
+                    for kk, a in zip(bucket_keys(k, self.zoo.n_buckets),
+                                     acts))
+            with obs.span("sac.scan"):
+                (self.actor, self.critic, self.opt_a, self.opt_c,
+                 cl, al, en) = self._update_scan(
+                    self.actor, self.critic, self.opt_a, self.opt_c,
+                    acts, rews, noise)
+            with obs.span("sac.read"):
+                out = {"critic_loss": obs.device_read(float, cl),
+                       "actor_loss": obs.device_read(float, al),
+                       "entropy": obs.device_read(float, en)}
             sp.set(**out)
             return out

@@ -1,6 +1,6 @@
 """Flight recorder: process-wide metrics registry + span tracer for the
 EGRL loop and the placement service.  Dependency-free (stdlib only;
-jax is imported lazily inside the optional profiler hook).
+never imports jax — see the profiler bridge below).
 
 Mode (``REPRO_OBS``, parsed fail-loud via utils/envpolicy.py):
 
@@ -14,10 +14,14 @@ Mode (``REPRO_OBS``, parsed fail-loud via utils/envpolicy.py):
   at ``REPRO_OBS_PATH`` (default ``obs_trace.jsonl``), consumed by
   tools/trace_report.py.
 
-``REPRO_OBS_PROFILE=<dir>`` additionally brackets the FIRST EGRL
-generation of the process with ``jax.profiler`` start/stop_trace (one
-generation keeps the device trace small; failures degrade to a warning
-— profiling must never take the training loop down).
+Profiler bridge, in every mode: while a ``jax.profiler`` session
+records (``jax.profiler.trace`` / ``start_trace``), each span also
+enters a ``jax.profiler.TraceAnnotation`` named ``obs/<name>``, so the
+program's spans sit in the device trace on the device events' clock.
+Off mode with no session recording still hands back ``NOOP_SPAN``, at
+the cost of the mode check plus one ``TraceAnnotation.is_enabled()``.
+The annotation type is looked up only once jax is already imported:
+without jax no profiler can be recording.
 
 Span taxonomy and event schema: docs/observability.md.
 
@@ -39,14 +43,15 @@ environment policy.
 from __future__ import annotations
 
 import os
+import sys
 from contextlib import contextmanager
 from typing import Callable, List, Optional
 
 from repro.obs.log import Logger, get_logger, set_quiet          # noqa: F401
 from repro.obs.metrics import (Counter, Gauge, Histogram,        # noqa: F401
                                MetricsRegistry, log_edges)
-from repro.obs.trace import (NOOP_SPAN, JsonlSink, RingSink,     # noqa: F401
-                             Span, Tracer)
+from repro.obs.trace import (NOOP_SPAN, JsonlSink,              # noqa: F401
+                             ProfilerSpan, RingSink, Span, Tracer)
 from repro.utils.envpolicy import env_policy
 
 DEFAULT_PATH = "obs_trace.jsonl"
@@ -139,13 +144,35 @@ def enabled() -> bool:
     return _state().mode != "off"
 
 
+def _recording():
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None (and None while jax is not imported)."""
+    ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return ann if ann is not None and ann.is_enabled() else None
+
+
 def span(name: str, **attrs):
-    """A context-manager span, or the no-op singleton when tracing is
-    off — the one mode check on the hot path."""
+    """A context-manager span, also written into the profiler's trace as
+    ``obs/<name>`` while a session records; the no-op singleton when
+    tracing is off and no session records."""
     st = _state()
+    ann = _recording()
+    if ann is not None:
+        ann = ann("obs/" + name, **attrs)
     if st.mode == "off":
-        return NOOP_SPAN
-    return st.tracer.span(name, **attrs)
+        return NOOP_SPAN if ann is None else ProfilerSpan(ann)
+    sp = st.tracer.span(name, **attrs)
+    sp._ann = ann
+    return sp
+
+
+def device_read(fetch: Callable, x):
+    """``fetch(x)`` for one blocking device-to-host read (``np.asarray``,
+    ``float``) of a generation: counted in ``egrl.device_reads`` and
+    spanned as ``device_read``.  Returns ``fetch(x)`` unchanged."""
+    _REGISTRY.counter("egrl.device_reads").inc()
+    with span("device_read"):
+        return fetch(x)
 
 
 def emit_event(event: dict) -> None:
@@ -189,36 +216,3 @@ def emit_metrics(reg: Optional[MetricsRegistry] = None) -> None:
     process-wide registry); no-op when off."""
     emit_event({"type": "metrics",
                 "snapshot": (reg if reg is not None else _REGISTRY).snapshot()})
-
-
-_PROFILED = False
-
-
-@contextmanager
-def profile_block():
-    """``REPRO_OBS_PROFILE=<dir>``: bracket the wrapped block — the
-    FIRST EGRL generation of the process — with a jax.profiler trace.
-    Without the env var (or after the first use) this is a no-op; a
-    profiler failure logs a warning and the block runs untraced."""
-    global _PROFILED
-    outdir = os.environ.get("REPRO_OBS_PROFILE")
-    if not outdir or _PROFILED:
-        yield
-        return
-    _PROFILED = True
-    import jax
-    try:
-        jax.profiler.start_trace(outdir)
-    except Exception as e:
-        get_logger("obs").warning(
-            f"REPRO_OBS_PROFILE: could not start jax profiler trace: {e}")
-        yield
-        return
-    try:
-        yield
-    finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            get_logger("obs").warning(
-                f"REPRO_OBS_PROFILE: could not stop jax profiler trace: {e}")

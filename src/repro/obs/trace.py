@@ -19,14 +19,20 @@ monotonic-clock durations and structured attributes.
   JSONL file in ``jsonl`` mode so a crashed process still leaves a
   readable trace.
 
+- **The profiler bridge**: while a ``jax.profiler`` session records,
+  every span also holds a ``jax.profiler.TraceAnnotation`` named
+  ``obs/<name>`` (``repro.obs.span`` makes it, in every mode), so the
+  span lands in the profiler's trace on the clock of the device events;
+  ``set`` passes attributes on to the annotation's metadata.
+
 Event schema (see docs/observability.md):
 
     {"type": "span", "name": ..., "id": int, "parent": int|null,
      "ts": seconds-since-tracer-epoch, "dur_ms": float, "attrs": {...}}
 
-The off-mode hot path never reaches this module: ``repro.obs.span``
-returns the shared ``NOOP_SPAN`` singleton — no allocation, no clock
-read, no sink touch.
+The off-mode hot path never reaches this module: with no profiler
+recording, ``repro.obs.span`` returns the shared ``NOOP_SPAN``
+singleton — no allocation, no clock read, no sink touch.
 """
 from __future__ import annotations
 
@@ -102,11 +108,31 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class ProfilerSpan:
+    """An off-mode span while a profiler session records: only the
+    profiler annotation, no event in any sink."""
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> None:
+        self._ann.set_metadata(**attrs)
+
+
 class Span:
     """One timed region.  Use as a context manager; ``set(**attrs)``
     attaches attributes at any point before close (e.g. outcomes known
     only at the end of the block)."""
-    __slots__ = ("_tracer", "name", "id", "parent", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "id", "parent", "attrs", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -115,17 +141,24 @@ class Span:
         self.id: Optional[int] = None
         self.parent: Optional[int] = None
         self._t0 = 0.0
+        self._ann = None          # profiler annotation (repro.obs.span)
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
     def __enter__(self) -> "Span":
         self._tracer._open(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc is not None:
             self.attrs["error"] = f"{exc_type.__name__}: {exc}"
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._tracer._close(self)
         return False
 

@@ -298,3 +298,72 @@ def test_tracer_is_thread_safe():
     assert sum(e["name"] == "main_inner" for e in events) == 200
     assert {e["name"] for e in events} >= {"w_outer", "w_inner",
                                            "main_outer"}
+
+
+# -------------------------------------------------------- profiler bridge
+
+def test_repro_obs_imports_no_jax():
+    """The recorder stays dependency-free: the profiler bridge looks the
+    annotation type up only once jax is loaded."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.dirname(os.path.dirname(os.path.dirname(obs.__file__)))
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, repro.obs; print('jax' in sys.modules)"],
+        capture_output=True, text=True, check=True,
+        env=dict(os.environ, PYTHONPATH=src))
+    assert p.stdout.strip() == "False"
+
+
+@pytest.mark.parametrize("mode", ["off", "mem"])
+def test_spans_enter_the_profiler_trace_while_it_records(mode, tmp_path):
+    """While a profiler session records, a span also writes an
+    ``obs/<name>`` annotation (off mode included, without any sink
+    event); ``set`` passes on to its metadata; mem-mode ring events keep
+    their schema; with the session stopped, off mode is NOOP_SPAN again."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with obs.override(mode=mode):
+        assert (obs.span("before") is NOOP_SPAN) == (mode == "off")
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.span("outer", rows=3) as sp:
+                with obs.span("inner"):
+                    pass
+                sp.set(best=1.5)
+        assert (obs.span("after") is NOOP_SPAN) == (mode == "off")
+        ev = obs.drain()
+    if mode == "off":
+        assert ev == []
+    else:
+        assert [(e["name"], e["attrs"]) for e in ev] == [
+            ("inner", {}), ("outer", {"rows": 3, "best": 1.5})]
+        assert all(set(e) == {"type", "name", "id", "parent", "ts",
+                              "dur_ms", "attrs"} for e in ev)
+    pd = ProfileData.from_file(glob.glob(
+        str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
+    got = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+           for p in pd.planes for ln in p.lines for e in ln.events
+           if e.name.startswith("obs/")}
+    assert set(got) == {"obs/outer", "obs/inner"}
+    assert got["obs/outer"][0] <= got["obs/inner"][0] <= \
+        got["obs/inner"][1] <= got["obs/outer"][1]
+
+
+def test_device_read_counts_and_returns_the_value():
+    import jax.numpy as jnp
+    x = jnp.arange(5, dtype=jnp.float32) / 3
+    reads = obs.counter("egrl.device_reads")
+    before = reads.value
+    with obs.override(mode="mem"):
+        a = obs.device_read(np.asarray, x)
+        f = obs.device_read(float, x[1])
+        ev = obs.drain()
+    assert np.array_equal(a, np.asarray(x)) and f == float(x[1])
+    assert reads.value - before == 2
+    assert [e["name"] for e in ev] == ["device_read", "device_read"]
