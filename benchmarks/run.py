@@ -380,7 +380,7 @@ def _bucket_dispatch_child() -> None:
     import numpy as np
 
     import jax
-    from repro.core.egrl import EGRLConfig, ZooEGRL
+    from repro.core.egrl import _SAMPLE_ACTIONS, EGRLConfig, ZooEGRL
     from repro.distributed.dispatch import autotune_bucket_k
     from repro.graphs.bucketed import bucket_keys_batch
     from repro.graphs.zoo import WORKLOADS, bert, resnet50, tiny_gpt
@@ -425,16 +425,16 @@ def _bucket_dispatch_child() -> None:
     pop = asyncd.gnn_pop
     keys = jax.random.split(jax.random.PRNGKey(1), pop.shape[0])
 
+    bkeys = bucket_keys_batch(keys, serial.zoo.n_buckets)
+
     def async_pipe():
         lg = dsp.forward(pop)
-        maps = dsp.sample(keys, lg)
+        maps = dsp.sample(bkeys, lg)
         jax.block_until_ready(dsp.evaluate(maps, cfg.reward_scale)["reward"])
 
     def serial_pipe():
         lgs = [f(serial.gnn_pop) for f in serial._pop_logits]
-        maps = tuple(serial._pop_sample(kc, lg) for kc, lg in
-                     zip(bucket_keys_batch(keys, serial.zoo.n_buckets),
-                         lgs))
+        maps = tuple(_SAMPLE_ACTIONS(kc, lg) for kc, lg in zip(bkeys, lgs))
         jax.block_until_ready(evaluate_population_bucketed(
             serial.zoo, maps, cfg.reward_scale)["reward"])
 

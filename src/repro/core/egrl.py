@@ -5,19 +5,27 @@ GNN->Boltzmann prior seeding.
 Device-resident generation (beyond-paper optimization): the population
 is stored as stacked arrays — GNN genomes as one (n_g, V) flat-parameter
 matrix, Boltzmann genomes as one (n_b, F) flat matrix — and a generation
-is a handful of jitted device calls:
+is a series of jitted device calls:
 
-1. ONE vmapped GNN forward over the stacked parameter matrix,
+1. ONE vmapped GNN forward over the stacked parameter matrix (one per
+   size bucket in the zoo driver),
 2. ONE vmapped Boltzmann sample (+ one batched PG rollout sample),
 3. one vmapped simulator call per population part (memsim.simulator;
    GNN / Boltzmann / PG mappings are scored separately so the sharded
    parts keep their ("pop",) placement — see generation()),
 4. ONE jitted EA step (core/ea.py: tournament, crossover, seeding,
-   mutation over the stacked genomes) plus an in-place migration row
+   mutation over the stacked genomes) plus a jitted migration row
    write for the PG policy.
 
-The only host<->device traffic per generation is the single sync that
-pulls (mappings, rewards) out for the replay buffer, best-mapping
+The zoo driver runs the glue between those programs (every key split of
+the generation, the samplers' bucket slices, the EA step's seeding
+grid, the zoo-order gathers, the fitness means, the actor flatten of
+the migration) inside a few more jitted programs: a warm 3-bucket egrl
+generation launches 36 programs, 13 of them the SAC learner's batch
+upload (tests/test_generation_launches.py), where the eager glue made
+114.  Results come back to the host in one blocking read per result
+array (21 per 3-bucket egrl generation, counted by the
+``egrl.device_reads`` counter), for the replay buffer, best-mapping
 tracking and logging.  The seed implementation instead kept a Python
 list of per-individual genomes: building each child ran 1-3 host RNG
 ops plus device transfers, serializing the inner loop.
@@ -140,9 +148,16 @@ _POP_LOGITS = jax.jit(gnn.population_logits, static_argnames=("backend",))
 _POP_LOGITS_ZOO = jax.jit(gnn.population_logits_zoo,
                           static_argnames=("backend",))
 _SAMPLE_ACTIONS = jax.jit(jax.vmap(gnn.sample_actions))
-# PG migration: row write at a traced index (one executable per pop
-# geometry, shared by every driver instance)
-_MIGRATE_ROW = jax.jit(lambda pop, vec, idx: pop.at[idx].set(vec))
+
+
+@partial(jax.jit, static_argnames=("idx",))
+def _migrate_row(pop, params, idx):
+    """PG migration: flatten the learner's actor (or take a flat (V,)
+    genome as is: a lone array flattens to itself) and write it into
+    row ``idx`` (static: no scalar upload per call).  One executable
+    per pop geometry and parameter structure, shared by every driver
+    instance."""
+    return pop.at[idx].set(gnn.flatten_params(params))
 
 
 @jax.jit
@@ -152,6 +167,76 @@ def _bz_sample_pop(keys, pops):
     linear), so one program serves every driver geometry."""
     n = pops.shape[-1] // bz.flat_size(1)
     return jax.vmap(lambda k, f: bz.sample(k, bz.from_flat(f, n)))(keys, pops)
+
+
+# ---------------------------------------------------------------------------
+# ZooEGRL's per-generation glue, one program per step.  Run eagerly,
+# every key split, slice, reshape and concatenate between the real
+# programs is a launch of its own, and the device idles while the host
+# dispatches it.  These programs only split keys and move data, so they
+# give the eager sequence's values bit for bit
+# (tests/test_generation_launches.py).
+
+@partial(jax.jit, static_argnames=("n_g", "n_g_pad", "n_b", "n_b_pad",
+                                   "n_buckets"))
+def _generation_keys(key, *, n_g, n_g_pad, n_b, n_b_pad, n_buckets):
+    """Every key a zoo generation draws from the driver's stream, in the
+    eager order (GNN rows, Boltzmann rows, EA step): returns the next
+    driver key and a dict with the per-bucket GNN row keys ``"g"``
+    (``bucket_keys_batch`` of the padded row keys), the padded
+    Boltzmann row keys ``"b"`` and the EA step's key ``"evolve"``.
+    Row keys are split with the REAL count (split(k, n) has no prefix
+    property) and repeated into the padding rows."""
+    out = {}
+    if n_g:
+        key, k = jax.random.split(key)
+        out["g"] = tuple(bucket_keys_batch(
+            _pad_keys(jax.random.split(k, n_g), n_g_pad), n_buckets))
+    if n_b:
+        key, k = jax.random.split(key)
+        out["b"] = _pad_keys(jax.random.split(k, n_b), n_b_pad)
+    if n_g or n_b:
+        key, out["evolve"] = jax.random.split(key)
+    return key, out
+
+
+def _seeding_grid(logits):
+    """Per-bucket (P, G_k, N_max_k, 2, 3) logits -> the bucket-major
+    (P, n_eff, 2, 3) grid the EA step seeds Boltzmann genomes from
+    (matching the bz genome layout; a flat reshape at K = 1)."""
+    return jnp.concatenate([lg.reshape(lg.shape[0], -1, 2, 3)
+                            for lg in logits], axis=1)
+
+
+_SEEDING_GRID = jax.jit(_seeding_grid)
+
+
+@jax.jit
+def _sample_gnn_rollouts(bucket_keys, logits):
+    """Per-bucket GNN rollout mappings (one vmapped sample per bucket
+    from its row keys) and the EA step's seeding grid, in one launch."""
+    maps = tuple(jax.vmap(gnn.sample_actions)(k, lg)
+                 for k, lg in zip(bucket_keys, logits))
+    return maps, _seeding_grid(logits)
+
+
+@partial(jax.jit, static_argnames=("layout",))
+def _sample_bz_rollouts(keys, pops, layout):
+    """One flat (n_eff, 2) Boltzmann sample per genome, split into the
+    per-bucket (P, G_k, N_max_k, 2) stacks; ``layout`` is the buckets'
+    (G_k, N_max_k), bucket-major."""
+    flat = _bz_sample_pop(keys, pops)
+    out, off = [], 0
+    for g, n in layout:
+        out.append(flat[:, off:off + g * n].reshape(-1, g, n, 2))
+        off += g * n
+    return tuple(out)
+
+
+@partial(jax.jit, static_argnames=("mode",))
+def _fitness(rewards, mode):
+    """Per-part (P, G) rewards -> per-part fitness vectors."""
+    return tuple(aggregate_rewards(r, mode) for r in rewards)
 
 
 def _compile_tracked(fn, what, **attrs):
@@ -267,14 +352,16 @@ class _EvoPopulation:
             self._evolve = jax.jit(partial(
                 _evolve_with_fitness_mask, base_evolve,
                 self.n_g, self.n_g_pad, self.n_b, self.n_b_pad))
-            # PG migration: jitted row write into the last REAL GNN
-            # slot, landing back in the population sharding (a
-            # collective scatter, not a host copy).  Shared by EGRL and
+            # PG migration: jitted flatten + row write into the last
+            # REAL GNN slot, landing back in the population sharding (a
+            # collective scatter, not a host copy).  Takes the actor
+            # itself or its flat (V,) genome.  Shared by EGRL and
             # ZooEGRL — both learners' actors flatten to the same (V,)
             # genome encoding (GNN parameters are graph-size
             # independent).
             self._migrate = jax.jit(
-                lambda pop, vec: pop.at[self.n_g - 1].set(vec),
+                lambda pop, params: pop.at[self.n_g - 1].set(
+                    gnn.flatten_params(params)),
                 out_shardings=self.pop_sharding.sharding)
         else:
             self._evolve = _evolve_program(
@@ -282,8 +369,8 @@ class _EvoPopulation:
                 bz_nodes, self.e_g, self.e_b, cfg.tournament_k,
                 cfg.crossover_prob, cfg.mut_prob, cfg.mut_frac,
                 cfg.mut_std)
-            self._migrate = lambda pop, vec: _MIGRATE_ROW(
-                pop, vec, self.n_g - 1)
+            self._migrate = lambda pop, params: _migrate_row(
+                pop, params, idx=self.n_g - 1)
 
     # ------------------------------------------------------- warm start
     def _prior_logits(self, vec: jnp.ndarray) -> jnp.ndarray:
@@ -501,9 +588,8 @@ class EGRL(_EvoPopulation):
             if self.mode == "egrl" and n_g > self.e_g:
                 obs.counter("egrl.migrations").inc()
                 with obs.span("migrate"):
-                    self.gnn_pop = self._migrate(
-                        self.gnn_pop,
-                        gnn.flatten_params(self.learner.actor))
+                    self.gnn_pop = self._migrate(self.gnn_pop,
+                                                 self.learner.actor)
         obs.gauge("egrl.replay_occupancy").set(len(self.buffer))
 
         rec = {
@@ -652,26 +738,14 @@ class ZooEGRL(_EvoPopulation):
             partial(_POP_LOGITS_ZOO, self._template, b.feats, b.adj,
                     b.node_mask, b.n_nodes)
             for b in self.zoo.buckets]
-        # one key per genome samples all G graphs' sub-actions; with
-        # K > 1 buckets the genome key is split once per bucket
-        # (bucket_keys_batch; K == 1 passes the keys through unchanged)
-        self._pop_sample = _SAMPLE_ACTIONS
-        # Boltzmann: ONE flat (n_eff, 2) sample per genome (module-level
-        # jit), split eagerly into the per-bucket (G_k, N_max_k, 2)
-        # stacks (bucket-major layout; a single bucket reduces to the
-        # flat reshape — device slices, bitwise the same rows)
-        offs = np.concatenate(
-            [[0], np.cumsum([b.n_graphs * b.n_max
-                             for b in self.zoo.buckets])])
-
-        def boltz_split(flat):                  # (P, n_eff, 2)
-            return tuple(
-                flat[:, offs[k]:offs[k + 1]].reshape(
-                    -1, b.n_graphs, b.n_max, 2)
-                for k, b in enumerate(self.zoo.buckets))
-
-        self._pop_boltz = lambda ks, pops: boltz_split(
-            _bz_sample_pop(ks, pops))
+        # Boltzmann genomes sample ONE flat (n_eff, 2) grid, split into
+        # per-bucket (G_k, N_max_k, 2) stacks by this bucket-major layout
+        # (a single bucket reduces to the flat reshape)
+        self._bz_layout = tuple((b.n_graphs, b.n_max)
+                                for b in self.zoo.buckets)
+        # what the EA step takes for a part this driver does not have
+        self._no_fitness = jnp.zeros((0,), jnp.float32)
+        self._no_grid = jnp.zeros((0, self.n_eff, 2, 3))
 
         # bucket-parallel dispatch (PR 10): place each bucket's pipeline
         # on its own device so generation wall time approaches the
@@ -718,6 +792,15 @@ class ZooEGRL(_EvoPopulation):
         with obs.span("generation", driver="zoo", mode=self.mode) as sp:
             return self._generation(sp)
 
+    def _draw_keys(self) -> Dict:
+        """Every key this generation draws from the driver's stream, in
+        one launch (``_generation_keys``); the serial, sharded and
+        dispatch paths all take their keys from it."""
+        self.key, keys = _generation_keys(
+            self.key, n_g=self.n_g, n_g_pad=self.n_g_pad, n_b=self.n_b,
+            n_b_pad=self.n_b_pad, n_buckets=self.zoo.n_buckets)
+        return keys
+
     def _generation(self, sp) -> Dict:
         cfg = self.cfg
         n_g, n_b = self.n_g, self.n_b
@@ -725,22 +808,22 @@ class ZooEGRL(_EvoPopulation):
         # parts[name]: per-bucket tuple of (P_pad, G_k, N_max_k, 2)
         parts, results = {}, {}
         real = {"g": n_g, "b": n_b}
-        logits_g = None
+        grid = self._no_grid
+        keys = {}
         dsp = self.dispatch
         if n_g:
             with obs.span("rollout.gnn", rows=n_g,
                           dispatch=dsp is not None):
+                keys = self._draw_keys()
                 if dsp is not None:
-                    # per-bucket forwards issued on their own devices
-                    # (donated population replicas); logits pulled back
-                    # to the primary device only for the EA step's
-                    # bucket-major concat.  Same programs, same key
-                    # split — bitwise the serial path's values.
+                    # per-bucket forwards and samples issued on their
+                    # own devices (donated population replicas); logits
+                    # pulled back to the primary device only for the EA
+                    # step's seeding grid.  Same programs, same keys —
+                    # bitwise the serial path's values.
                     logits_dev = dsp.forward(self.gnn_pop)
-                    keys = _pad_keys(jax.random.split(self._k(), n_g),
-                                     self.n_g_pad)
-                    parts["g"] = dsp.sample(keys, logits_dev)
-                    logits_g = dsp.pull(logits_dev)
+                    parts["g"] = dsp.sample(keys["g"], logits_dev)
+                    grid = _SEEDING_GRID(dsp.pull(logits_dev))
                 else:
                     # 2-D mesh: dominant buckets take the wide row
                     # layout (rows over pop*model devices), the rest
@@ -751,17 +834,14 @@ class ZooEGRL(_EvoPopulation):
                         f(wide_pop if self._wide_bucket[k]
                           else self.gnn_pop)
                         for k, f in enumerate(self._pop_logits)]
-                    keys = _pad_keys(jax.random.split(self._k(), n_g),
-                                     self.n_g_pad)
-                    parts["g"] = tuple(
-                        self._pop_sample(kc, lg) for kc, lg in
-                        zip(bucket_keys_batch(keys, zoo.n_buckets),
-                            logits_g))
+                    parts["g"], grid = _sample_gnn_rollouts(keys["g"],
+                                                            logits_g)
         if n_b:
             with obs.span("rollout.boltzmann", rows=n_b):
-                parts["b"] = self._pop_boltz(_pad_keys(
-                    jax.random.split(self._k(), n_b), self.n_b_pad),
-                    self.bz_pop)
+                if not keys:
+                    keys = self._draw_keys()
+                parts["b"] = _sample_bz_rollouts(keys["b"], self.bz_pop,
+                                                 self._bz_layout)
         if self.mode != "ea":
             with obs.span("rollout.pg", rows=cfg.pg_rollouts):
                 parts["pg"] = self.learner.explore_actions(cfg.pg_rollouts)
@@ -774,24 +854,15 @@ class ZooEGRL(_EvoPopulation):
                         zoo, maps, cfg.reward_scale))  # (P_pad, G) zoo order
 
         # ---- EA step on the aggregate fitness, still on device
-        empty = jnp.zeros((0,), jnp.float32)
         with obs.span("fitness"):
-            fit = {name: aggregate_rewards(results[name]["reward"],
-                                           self.agg)
-                   for name in parts}
+            fit = dict(zip(parts, _fitness(
+                tuple(results[n]["reward"] for n in parts), self.agg)))
         if n_g or n_b:
             with obs.span("evolve"):
                 self.gnn_pop, self.bz_pop = self._evolve(
-                    self._k(),
-                    self.gnn_pop, fit.get("g", empty),
-                    self.bz_pop, fit.get("b", empty),
-                    # Boltzmann-seeding grid: bucket-major
-                    # (P, n_eff, 2, 3), matching the bz genome layout
-                    # (flat reshape at K = 1)
-                    jnp.concatenate([lg.reshape(self.n_g_pad, -1, 2, 3)
-                                     for lg in logits_g], axis=1)
-                    if logits_g is not None
-                    else jnp.zeros((0, self.n_eff, 2, 3)))
+                    keys["evolve"],
+                    self.gnn_pop, fit.get("g", self._no_fitness),
+                    self.bz_pop, fit.get("b", self._no_fitness), grid)
 
         # ---- host sync: one blocking read per result array (3 per
         # part, plus one per part and bucket for the rollout rows)
@@ -841,9 +912,8 @@ class ZooEGRL(_EvoPopulation):
             if self.mode == "egrl" and n_g > self.e_g:
                 obs.counter("egrl.migrations").inc()
                 with obs.span("migrate"):
-                    self.gnn_pop = self._migrate(
-                        self.gnn_pop,
-                        gnn.flatten_params(self.learner.actor))
+                    self.gnn_pop = self._migrate(self.gnn_pop,
+                                                 self.learner.actor)
         if self.bank is not None:
             obs.gauge("egrl.replay_occupancy").set(len(self.bank))
 

@@ -34,6 +34,7 @@ Two learners share the same losses and the same one-jitted-scan update
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict
 
 import numpy as np
@@ -247,6 +248,25 @@ class SACLearner:
             return out
 
 
+@partial(jax.jit, static_argnames=("n",))
+def _zoo_explore(actor, key, buckets, n):
+    """``ZooSAC.explore_actions`` in one launch: the learner key's two
+    splits, then per rollout key the actor's zoo forward and a sample
+    per bucket (``bucket_keys``).  Returns the next learner key and the
+    per-bucket (n, G_k, N_max_k, 2) actions.  Module-level, with the
+    buckets as arguments, so every learner over a bucket geometry
+    shares the executable."""
+    key, k = jax.random.split(key)
+
+    def sample_one(kk):
+        ks = bucket_keys(kk, len(buckets))
+        return tuple(gnn.sample_actions(kb, gnn.gnn_forward_zoo(
+            actor, fe, ad, li, nr))
+            for kb, (fe, ad, li, nr) in zip(ks, buckets))
+
+    return key, jax.vmap(sample_one)(jax.random.split(k, n))
+
+
 class ZooSAC:
     """Multi-workload SAC learner over a size-bucketed zoo — the PG
     member of ``ZooEGRL``.
@@ -335,14 +355,7 @@ class ZooSAC:
             gnn.gnn_forward_zoo(ap, fe, ad, li, nr)
             for fe, ad, li, nr in buckets))
 
-        def sample_one(ap, k):
-            ks = bucket_keys(k, n_buckets)
-            return tuple(gnn.sample_actions(kk, gnn.gnn_forward_zoo(
-                ap, fe, ad, li, nr))
-                for kk, (fe, ad, li, nr) in zip(ks, buckets))
-
-        self._sample_batch = jax.jit(
-            lambda ap, ks: jax.vmap(lambda k: sample_one(ap, k))(ks))
+        self._buckets = buckets
 
     def policy_logits(self, params=None):
         """Per-bucket (G_k, N_max_k, 2, 3) zoo logits tuple (padding
@@ -355,8 +368,9 @@ class ZooSAC:
         at once (a K==1 zoo consumes the key unchanged — bit-identical
         to the flat path; padding rows sample throwaway uniform actions
         — inert downstream)."""
-        self.key, k = jax.random.split(self.key)
-        return self._sample_batch(self.actor, jax.random.split(k, n))
+        self.key, acts = _zoo_explore(self.actor, self.key, self._buckets,
+                                      n=n)
+        return acts
 
     def update(self, bank: ReplayBank, steps: int) -> Dict[str, float]:
         """``steps`` zoo-wide gradient steps in one jitted scan, each on

@@ -68,7 +68,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core import gnn
-from repro.memsim.batch import evaluate_population_zoo
+from repro.memsim.batch import SCALARS, evaluate_population_zoo
 from repro.utils.envpolicy import env_policy
 
 # The donated population replica rarely aliases an output buffer (the
@@ -196,15 +196,14 @@ class BucketDispatcher:
                                        b.n_nodes, replica))
         return out
 
-    def sample(self, keys: jnp.ndarray,
+    def sample(self, bucket_keys: Sequence[jnp.ndarray],
                logits: Sequence[jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
-        """Per-bucket action sampling next to the logits.  The key split
-        is the serial path's ``bucket_keys_batch`` (same values), each
-        chunk shipped to its bucket's device."""
-        from repro.graphs.bucketed import bucket_keys_batch
+        """Per-bucket action sampling next to the logits.  ``bucket_keys``
+        are the serial path's per-bucket row keys (``bucket_keys_batch``
+        of the padded row keys, same values), each shipped to its
+        bucket's device."""
         out = []
-        for kc, lg, dev in zip(bucket_keys_batch(keys, self.zoo.n_buckets),
-                               logits, self.bucket_device):
+        for kc, lg, dev in zip(bucket_keys, logits, self.bucket_device):
             out.append(_SAMPLE(jax.device_put(kc, dev), lg))
         return tuple(out)
 
@@ -227,9 +226,9 @@ class BucketDispatcher:
             dev = self.bucket_device[k]
             per.append(evaluate_population_zoo(
                 self._staged[k], jax.device_put(m, dev), reward_scale))
-        out = {key: self.zoo.gather_zoo(
-                   [jax.device_put(r[key], self.primary) for r in per])
-               for key in ("reward", "eps", "latency", "speedup", "valid")}
+        out = self.zoo.gather_zoo(
+            [{key: jax.device_put(r[key], self.primary) for key in SCALARS}
+             for r in per])
         out["rectified"] = tuple(r["rectified"] for r in per)
         return out
 

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import List, Sequence, Tuple, Union
 
 import numpy as np
@@ -121,6 +122,16 @@ def bucket_keys_batch(keys: jnp.ndarray, n_buckets: int) -> List[jnp.ndarray]:
     return [split[:, k] for k in range(n_buckets)]
 
 
+@partial(jax.jit, static_argnames=("perm",))
+def _gather_zoo(per_bucket, perm):
+    """``BucketedZoo.gather_zoo``'s program: leaf by leaf, concatenate
+    the buckets along the graph axis and take ``perm``."""
+    idx = np.asarray(perm, np.int32)
+    return jax.tree.map(
+        lambda *xs: jnp.take(jnp.concatenate(xs, axis=-1), idx, axis=-1),
+        *per_bucket)
+
+
 @dataclasses.dataclass(frozen=True)
 class BucketedZoo:
     """K per-size-class GraphBatches + zoo-order index maps."""
@@ -180,12 +191,16 @@ class BucketedZoo:
         return np.asarray([offs[b] + s for b, s in
                            zip(self.graph_bucket, self.graph_slot)], np.int32)
 
-    def gather_zoo(self, per_bucket: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    def gather_zoo(self, per_bucket: Sequence):
         """Per-bucket (..., G_k) arrays -> one (..., G) array in ZOO
-        order.  A concat + exact gather: values are bit-identical, and a
-        single-bucket zoo reduces to an identity permutation."""
-        cat = jnp.concatenate(list(per_bucket), axis=-1)
-        return jnp.take(cat, jnp.asarray(self.zoo_perm()), axis=-1)
+        order; or per-bucket pytrees of such arrays (a dict of results)
+        -> one pytree, every leaf gathered in the same program.  A
+        concat + exact gather: values are bit-identical, and a
+        single-bucket zoo reduces to an identity permutation.  The
+        permutation is a constant of the jitted program, so a call is
+        one launch and no upload."""
+        return _gather_zoo(tuple(per_bucket),
+                           tuple(int(i) for i in self.zoo_perm()))
 
     def split_zoo_mappings(self, maps: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
         """Flat zoo-order mappings (..., G, N_max, 2) -> per-bucket

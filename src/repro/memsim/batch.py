@@ -88,6 +88,10 @@ def evaluate_population_zoo(gb: GraphBatch, mappings: jnp.ndarray,
 
 
 # ------------------------------------------------------- bucketed path
+# the per-graph results gathered back to zoo order, in one program
+SCALARS = ("reward", "eps", "latency", "speedup", "valid")
+
+
 def rectify_bucketed(bz: BucketedZoo, mappings: Sequence[jnp.ndarray]):
     """Per-bucket mappings [(G_k, N_max_k, 2), ...] -> (per-bucket
     rectified tuple, eps (G,) in ZOO order)."""
@@ -114,8 +118,7 @@ def evaluate_bucketed(bz: BucketedZoo, mappings: Sequence[jnp.ndarray],
     ``rectified`` tuple."""
     per = [evaluate_zoo(gb, m, reward_scale)
            for gb, m in zip(bz.buckets, mappings)]
-    out = {k: bz.gather_zoo([r[k] for r in per])
-           for k in ("reward", "eps", "latency", "speedup", "valid")}
+    out = bz.gather_zoo([{k: r[k] for k in SCALARS} for r in per])
     out["rectified"] = tuple(r["rectified"] for r in per)
     return out
 
@@ -128,15 +131,15 @@ def evaluate_population_bucketed(bz: BucketedZoo,
     mappings: per-bucket (P, G_k, N_max_k, 2) stacks -> dict of (P, G)
     zoo-order arrays (+ per-bucket ``rectified``).  Each bucket call is
     the cached ``evaluate_population_zoo`` executable for that bucket's
-    shape (K executables total, K static), and the population axis
-    keeps any ("pop",) sharding — the gather permutes only the trailing
-    graph axis.  Scalars are bit-exact vs evaluating the same rows
-    through the flat GraphBatch (see module docstring)."""
+    shape (K executables total, K static), then one gather program for
+    the five per-graph results; the population axis keeps any ("pop",)
+    sharding — the gather permutes only the trailing graph axis.
+    Scalars are bit-exact vs evaluating the same rows through the flat
+    GraphBatch (see module docstring)."""
     assert len(mappings) == bz.n_buckets, (len(mappings), bz.n_buckets)
     per = [evaluate_population_zoo(gb, m, reward_scale)
            for gb, m in zip(bz.buckets, mappings)]
-    out = {k: bz.gather_zoo([r[k] for r in per])
-           for k in ("reward", "eps", "latency", "speedup", "valid")}
+    out = bz.gather_zoo([{k: r[k] for k in SCALARS} for r in per])
     out["rectified"] = tuple(r["rectified"] for r in per)
     return out
 
