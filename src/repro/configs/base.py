@@ -33,6 +33,43 @@ class SSMCfg:
 
 
 @dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """The heads of one kind of attention layer.  ``window`` > 0 bounds
+    the keys a query sees, and so the decode cache, to the last
+    ``window`` tokens; ``sink`` adds one learned logit per query head to
+    the softmax's denominator."""
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int            # q and k
+    v_head_dim: int
+    window: int = 0
+    sink: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class Deployment:
+    """A stated deployment of one run shape: over how many chips each
+    layer is divided, and how.  ``graphs/extract.py`` gives one chip's
+    share of one pipeline stage.
+
+    Each stage holds ``n_layers / pipeline_stages`` consecutive layers
+    on ``tensor_parallel * data_parallel`` chips.  Attention is divided
+    by kv-head group (a chip holds ``n_heads / tp`` query heads and
+    ``ceil(n_kv_heads / tp)`` kv heads, replicated where tp exceeds
+    them), as are the dense FFN's width and the vocabulary; routed
+    experts are divided ``expert_parallel`` ways; the sequences
+    ``data_parallel`` ways.  ``expert_tokens`` is the number of tokens an
+    expert-parallel group routes in one step (one micro-batch), which
+    sets the chance that a step reaches each expert."""
+    shape: str                 # the SHAPES entry it serves
+    pipeline_stages: int
+    tensor_parallel: int
+    expert_parallel: int
+    data_parallel: int
+    expert_tokens: int
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | vlm | ssm | hybrid | encdec
@@ -50,6 +87,16 @@ class ModelConfig:
     tie_embeddings: bool = False
     moe: Optional[MoECfg] = None
     ssm: Optional[SSMCfg] = None
+    # v head width where it differs from the q/k width (0: head_dim)
+    v_head_dim: int = 0
+    # windowed attention: its heads, and per layer 1 where a layer uses
+    # them (0: full attention with the fields above); () = all full
+    swa: Optional[AttnKind] = None
+    layer_pattern: Tuple[int, ...] = ()
+    # leading layers with a dense FFN of width d_ff before the MoE layers
+    dense_layers: int = 0
+    # the stated deployment whose chip share graphs/extract.py places
+    deployment: Optional[Deployment] = None
     # hybrid (zamba2): one shared transformer block applied every k layers
     shared_attn_every: int = 0
     # encdec (seamless): layers are split enc/dec; n_layers == enc + dec
@@ -81,6 +128,17 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // max(self.n_kv_heads, 1)
 
+    def attn_kind(self, layer: int) -> AttnKind:
+        """The heads of ``layer``'s attention."""
+        if self.layer_pattern and self.layer_pattern[layer]:
+            return self.swa
+        return AttnKind(self.n_heads, self.n_kv_heads, self.head_dim,
+                        self.v_head_dim or self.head_dim)
+
+    def uses_moe(self, layer: int) -> bool:
+        return (self.moe is not None and layer >= self.dense_layers
+                and layer % self.moe.every == self.moe.every - 1)
+
     @property
     def act_dtype(self):
         return jnp.dtype(self.dtype)
@@ -104,7 +162,8 @@ SHAPES = {
     "long_500k": ShapeCfg("long_500k", 524288, 1, "decode"),
 }
 
-# archs with an O(S^2)-only attention path skip long_500k (see DESIGN.md §6)
+# archs with an O(S^2)-only attention path skip long_500k (see
+# docs/architecture.md, "Graph extraction")
 SUBQUADRATIC_FAMILIES = ("ssm", "hybrid")
 
 
@@ -115,7 +174,11 @@ def supports_shape(cfg: ModelConfig, shape: ShapeCfg) -> Tuple[bool, str]:
 
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:
-    """Reduced same-family config for CPU smoke tests."""
+    """Reduced same-family config for CPU smoke tests of the LLM stack
+    (``models/``).  That stack models none of the per-layer attention
+    kinds, the separate v width, the leading dense layers or a
+    deployment, so the smoke config drops them: a windowed MoE model
+    runs as a plain MoE model of its family."""
     kw = dict(
         n_layers=max(2, min(cfg.n_layers, 2)),
         d_model=64,
@@ -130,6 +193,11 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         grad_accum_microbatches=1,
         attn_chunk=32,
         logit_chunk=32,
+        v_head_dim=0,
+        swa=None,
+        layer_pattern=(),
+        dense_layers=0,
+        deployment=None,
     )
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(

@@ -16,6 +16,7 @@ ARCH_IDS = (
     "mamba2-780m",
     "zamba2-1.2b",
     "seamless-m4t-medium",
+    "mimo-v2-flash",
 )
 
 _MODULE = {a: "repro.configs." + a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}
@@ -32,7 +33,7 @@ def get_shape(name: str) -> ShapeCfg:
 
 
 def all_cells(include_skipped: bool = False):
-    """Yield (arch, shape, supported, reason) for the 40 assigned cells."""
+    """Yield (arch, shape, supported, reason) for every registry cell."""
     for a in ARCH_IDS:
         cfg = get_config(a)
         for s in SHAPES.values():

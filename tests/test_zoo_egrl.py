@@ -11,8 +11,9 @@ from repro.core.egrl import (EGRLConfig, ZooEGRL, evaluate_gnn_on,
 from repro.core.replay import ReplayBank, ReplayBuffer
 from repro.core.sac import SACConfig, SACLearner, ZooSAC
 from repro.graphs.batch import build_graph_batch
-from repro.graphs.zoo import (PAPER_WORKLOADS, SMALL_WORKLOADS,
-                              SYNTH_WORKLOADS, WORKLOADS, dense_cnn,
+from repro.graphs.zoo import (EXTRACTED_WORKLOADS, PAPER_WORKLOADS,
+                              SMALL_WORKLOADS, SYNTH_WORKLOADS, WORKLOADS,
+                              dense_cnn,
                               moe_transformer, resnet50, resnet101,
                               workload_sizes)
 
@@ -20,7 +21,8 @@ from repro.graphs.zoo import (PAPER_WORKLOADS, SMALL_WORKLOADS,
 # ------------------------------------------------------- zoo registry
 def test_zoo_registry_contains_1k_graphs():
     assert (set(PAPER_WORKLOADS) | set(SYNTH_WORKLOADS)
-            | set(SMALL_WORKLOADS) == set(WORKLOADS))
+            | set(SMALL_WORKLOADS) | set(EXTRACTED_WORKLOADS)
+            == set(WORKLOADS))
     big = {name: f().n for name, f in SYNTH_WORKLOADS.items()}
     assert len(big) >= 2
     for name, n in big.items():
