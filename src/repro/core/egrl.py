@@ -10,9 +10,11 @@ is a series of jitted device calls:
 1. ONE vmapped GNN forward over the stacked parameter matrix (one per
    size bucket in the zoo driver),
 2. ONE vmapped Boltzmann sample (+ one batched PG rollout sample),
-3. one vmapped simulator call per population part (memsim.simulator;
-   GNN / Boltzmann / PG mappings are scored separately so the sharded
-   parts keep their ("pop",) placement — see generation()),
+3. one vmapped simulator call per population part in the per-graph
+   driver (memsim.simulator; GNN / Boltzmann / PG mappings are scored
+   separately so the sharded parts keep their ("pop",) placement — see
+   generation()); the zoo driver joins the parts' rows and scores them
+   in one simulator call per size bucket,
 4. ONE jitted EA step (core/ea.py: tournament, crossover, seeding,
    mutation over the stacked genomes) plus a jitted migration row
    write for the PG policy.
@@ -20,15 +22,16 @@ is a series of jitted device calls:
 The zoo driver runs the glue between those programs (every key split of
 the generation, the samplers' bucket slices, the EA step's seeding
 grid, the zoo-order gathers, the fitness means, the actor flatten of
-the migration) inside a few more jitted programs: a warm 3-bucket egrl
-generation launches 36 programs, 13 of them the SAC learner's batch
-upload (tests/test_generation_launches.py), where the eager glue made
-114.  Results come back to the host in one blocking read per result
-array (21 per 3-bucket egrl generation, counted by the
-``egrl.device_reads`` counter), for the replay buffer, best-mapping
-tracking and logging.  The seed implementation instead kept a Python
-list of per-individual genomes: building each child ran 1-3 host RNG
-ops plus device transfers, serializing the inner loop.
+the migration, the join of the parts' rollout rows) inside a few more
+jitted programs: a warm 3-bucket egrl generation launches 29 programs,
+13 of them the SAC learner's batch upload
+(tests/test_generation_launches.py), where the eager glue made 114.
+Results come back to the host in one blocking read per result array (9
+per 3-bucket egrl generation, counted by the ``egrl.device_reads``
+counter), for the replay buffer, best-mapping tracking and logging.
+The seed implementation instead kept a Python list of per-individual
+genomes: building each child ran 1-3 host RNG ops plus device
+transfers, serializing the inner loop.
 
 Population sharding (PR 2, padding PR 3): when more than one device is
 visible (see repro.distributed.population for the REPRO_POP_SHARDS
@@ -233,10 +236,31 @@ def _sample_bz_rollouts(keys, pops, layout):
     return tuple(out)
 
 
-@partial(jax.jit, static_argnames=("mode",))
-def _fitness(rewards, mode):
-    """Per-part (P, G) rewards -> per-part fitness vectors."""
-    return tuple(aggregate_rewards(r, mode) for r in rewards)
+def _merge_rollouts(parts, pad):
+    """Every part's per-bucket (P_part, G_k, N_max_k, 2) rollout stacks
+    joined along the row axis in part order, bucket by bucket, with
+    ``pad`` zero rows at the end of each bucket's stack: the rows of the
+    whole generation, scored in one simulator call per bucket."""
+    out = []
+    for stacks in zip(*parts):
+        rows = list(stacks)
+        if pad:
+            rows.append(jnp.zeros((pad,) + stacks[0].shape[1:],
+                                  stacks[0].dtype))
+        out.append(jnp.concatenate(rows))
+    return tuple(out)
+
+
+_MERGE_ROLLOUTS = jax.jit(_merge_rollouts, static_argnames=("pad",))
+
+
+@partial(jax.jit, static_argnames=("bounds", "mode"))
+def _fitness(rewards, bounds, mode):
+    """Merged (P_total, G) rewards -> each part's fitness vector (rows
+    ``start:stop`` of ``bounds``, part order) and their concatenation,
+    which the host reads in one go."""
+    fit = tuple(aggregate_rewards(rewards[a:b], mode) for a, b in bounds)
+    return fit, jnp.concatenate(fit)
 
 
 def _compile_tracked(fn, what, **attrs):
@@ -684,6 +708,17 @@ class ZooEGRL(_EvoPopulation):
     over the population axis, the EA step handles padded slots, and
     migration is a jitted row write with ``out_shardings`` pinned to
     the population sharding.
+
+    A generation's rollout rows — the GNN part, the Boltzmann part and
+    the PG rows, with their padding rows — are joined along the row axis
+    (part order g, b, pg) and scored in ONE simulator call per bucket: a
+    call's time follows its scan's sequential steps, not its rows, so
+    one call per part would pay every scan once per part.  Each part's
+    rows sit at a static offset of the joined stack; the fitness program
+    and the host read slice them back out.  Under ("pop",) sharding the
+    joined stack is padded to a multiple of the shard count, so it keeps
+    the row sharding; the host drops those rows like the parts' own
+    padding rows.
     """
 
     def __init__(self, graphs: Sequence[WorkloadGraph],
@@ -747,6 +782,28 @@ class ZooEGRL(_EvoPopulation):
         self._no_fitness = jnp.zeros((0,), jnp.float32)
         self._no_grid = jnp.zeros((0, self.n_eff, 2, 3))
 
+        # the generation's rollout rows, joined in part order: each
+        # part's first row, row count in the joined stack and real rows
+        # (the rest of a part are its padding rows); a sharded stack is
+        # padded to a multiple of the shard count to keep its sharding
+        rows = [("g", self.n_g_pad, self.n_g), ("b", self.n_b_pad, self.n_b),
+                ("pg", cfg.pg_rollouts if mode != "ea" else 0,
+                 cfg.pg_rollouts)]
+        self._parts, off = {}, 0
+        for name, n, real in rows:
+            if n:
+                self._parts[name] = (off, n, real)
+                off += n
+        self._fit_bounds = tuple((o, o + n) for o, n, _ in
+                                 self._parts.values())
+        self._merge_pad = 0
+        self._merge = _MERGE_ROLLOUTS
+        if self.pop_sharding.active:
+            self._merge_pad = -off % self.pop_sharding.n_shards
+            self._merge = jax.jit(_merge_rollouts, static_argnames=("pad",),
+                                  out_shardings=self.pop_sharding.sharding)
+        self._merged_rows = off + self._merge_pad
+
         # bucket-parallel dispatch (PR 10): place each bucket's pipeline
         # on its own device so generation wall time approaches the
         # slowest bucket, not the sum.  Mutually exclusive with the
@@ -806,8 +863,7 @@ class ZooEGRL(_EvoPopulation):
         n_g, n_b = self.n_g, self.n_b
         zoo = self.zoo
         # parts[name]: per-bucket tuple of (P_pad, G_k, N_max_k, 2)
-        parts, results = {}, {}
-        real = {"g": n_g, "b": n_b}
+        parts = {}
         grid = self._no_grid
         keys = {}
         dsp = self.dispatch
@@ -818,11 +874,13 @@ class ZooEGRL(_EvoPopulation):
                 if dsp is not None:
                     # per-bucket forwards and samples issued on their
                     # own devices (donated population replicas); logits
-                    # pulled back to the primary device only for the EA
-                    # step's seeding grid.  Same programs, same keys —
-                    # bitwise the serial path's values.
+                    # and samples pulled back to the primary device for
+                    # the EA step's seeding grid and the join of the
+                    # parts' rows.  Same programs, same keys — bitwise
+                    # the serial path's values.
                     logits_dev = dsp.forward(self.gnn_pop)
-                    parts["g"] = dsp.sample(keys["g"], logits_dev)
+                    parts["g"] = tuple(dsp.pull(
+                        dsp.sample(keys["g"], logits_dev)))
                     grid = _SEEDING_GRID(dsp.pull(logits_dev))
                 else:
                     # 2-D mesh: dominant buckets take the wide row
@@ -845,18 +903,20 @@ class ZooEGRL(_EvoPopulation):
         if self.mode != "ea":
             with obs.span("rollout.pg", rows=cfg.pg_rollouts):
                 parts["pg"] = self.learner.explore_actions(cfg.pg_rollouts)
-        with obs.span("evaluate", parts=len(parts),
+        with obs.span("evaluate", parts=len(parts), rows=self._merged_rows,
                       buckets=zoo.n_buckets, dispatch=dsp is not None):
-            for name, maps in parts.items():
-                results[name] = (
-                    dsp.evaluate(maps, cfg.reward_scale)
-                    if dsp is not None else evaluate_population_bucketed(
-                        zoo, maps, cfg.reward_scale))  # (P_pad, G) zoo order
+            # per-bucket (P_total, G_k, N_max_k, 2): every part's rows
+            # (the dispatcher ships each bucket's stack to its device)
+            merged = self._merge(tuple(parts.values()), pad=self._merge_pad)
+            results = (dsp.evaluate(merged, cfg.reward_scale)
+                       if dsp is not None else evaluate_population_bucketed(
+                           zoo, merged, cfg.reward_scale))  # zoo order
 
         # ---- EA step on the aggregate fitness, still on device
         with obs.span("fitness"):
-            fit = dict(zip(parts, _fitness(
-                tuple(results[n]["reward"] for n in parts), self.agg)))
+            fit, fit_all = _fitness(results["reward"], self._fit_bounds,
+                                    self.agg)
+            fit = dict(zip(self._parts, fit))
         if n_g or n_b:
             with obs.span("evolve"):
                 self.gnn_pop, self.bz_pop = self._evolve(
@@ -864,29 +924,29 @@ class ZooEGRL(_EvoPopulation):
                     self.gnn_pop, fit.get("g", self._no_fitness),
                     self.bz_pop, fit.get("b", self._no_fitness), grid)
 
-        # ---- host sync: one blocking read per result array (3 per
-        # part, plus one per part and bucket for the rollout rows)
-        def np_real(name, x):
-            a = obs.device_read(np.asarray, x)
-            return a[:real[name]] if name in real else a
+        # ---- host sync: one blocking read per result array (reward,
+        # valid, fitness and one per bucket for the rollout rows), then
+        # each part's real rows sliced out on the host, in part order
+        def real_rows(a):
+            return np.concatenate([a[o:o + r]
+                                   for o, _, r in self._parts.values()])
 
         with obs.span("host_sync"):
-            rewards = np.concatenate(    # (P, G) zoo order
-                [np_real(n, results[n]["reward"]) for n in parts])
-            per_part_fit = {n: np_real(n, fit[n]) for n in parts}
-            fitness = np.concatenate(list(per_part_fit.values()))
-            valid = np.concatenate(
-                [np_real(n, results[n]["valid"]) for n in parts])
+            read = partial(obs.device_read, np.asarray)
+            rewards = real_rows(read(results["reward"]))   # (P, G) zoo order
+            valid = real_rows(read(results["valid"]))
+            fit_np = read(fit_all)
             # per-bucket host copies of the rollout rows (real rows only)
-            maps_np = {name: [np_real(name, m) for m in bucket_maps]
-                       for name, bucket_maps in parts.items()}
+            maps_np = [real_rows(read(m)) for m in merged]
+            per_part_fit = {n: fit_np[o:o + r]
+                            for n, (o, _, r) in self._parts.items()}
+            fitness = np.concatenate(list(per_part_fit.values()))
         with obs.span("bookkeeping"):
             self.steps += rewards.size      # one env step per (genome, graph)
             # per-graph action stacks in the SAME part order as `rewards`
             # rows (g, b, pg) — graph gi's rows live at its (bucket, slot)
             acts_by_graph = [
-                np.concatenate([maps_np[name][zoo.graph_bucket[gi]]
-                                [:, zoo.graph_slot[gi]] for name in parts])
+                maps_np[zoo.graph_bucket[gi]][:, zoo.graph_slot[gi]]
                 for gi in range(self.n_graphs)]
             for gi in range(self.n_graphs):
                 b = int(np.argmax(rewards[:, gi]))

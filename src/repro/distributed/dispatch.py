@@ -219,13 +219,15 @@ class BucketDispatcher:
         sampler already put them there), evaluated against the STAGED
         bucket, and only the per-graph scalars are pulled back to the
         primary device for the zoo-order gather.  Same dict shape and
-        bitwise the same values as the serial path."""
+        bitwise the same values as the serial path, and the same count
+        in ``memsim.scan_calls``."""
         assert len(mappings) == self.zoo.n_buckets
         per = []
         for k, m in enumerate(mappings):
             dev = self.bucket_device[k]
             per.append(evaluate_population_zoo(
                 self._staged[k], jax.device_put(m, dev), reward_scale))
+        obs.counter("memsim.scan_calls").inc(len(per))
         out = self.zoo.gather_zoo(
             [{key: jax.device_put(r[key], self.primary) for key in SCALARS}
              for r in per])

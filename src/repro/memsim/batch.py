@@ -28,6 +28,13 @@ order regardless of ring width), eps divides by the host-precomputed
 ``total_bytes``, and latency reduces left-to-right — so re-padding a
 graph to its smaller bucket changes nothing bitwise
 (tests/test_bucketed_zoo.py sweeps the whole zoo).
+
+The zoo driver (``core.egrl.ZooEGRL``) joins a generation's population
+parts (GNN, Boltzmann, PG rows) and scores them in ONE call per bucket:
+a bucket's time is its scans' sequential steps, nearly whatever the row
+count, so a call per part paid every scan once per part.  The counter
+``memsim.scan_calls`` counts the per-bucket simulator programs this
+path launches.
 """
 from __future__ import annotations
 
@@ -37,6 +44,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graphs.batch import GraphBatch
 from repro.graphs.bucketed import BucketedZoo
 from repro.memsim.simulator import _rectify_scan, latency
@@ -135,10 +143,12 @@ def evaluate_population_bucketed(bz: BucketedZoo,
     the five per-graph results; the population axis keeps any ("pop",)
     sharding — the gather permutes only the trailing graph axis.
     Scalars are bit-exact vs evaluating the same rows through the flat
-    GraphBatch (see module docstring)."""
+    GraphBatch (see module docstring).  Each bucket call counts once in
+    ``memsim.scan_calls``."""
     assert len(mappings) == bz.n_buckets, (len(mappings), bz.n_buckets)
     per = [evaluate_population_zoo(gb, m, reward_scale)
            for gb, m in zip(bz.buckets, mappings)]
+    obs.counter("memsim.scan_calls").inc(len(per))
     out = bz.gather_zoo([{k: r[k] for k in SCALARS} for r in per])
     out["rectified"] = tuple(r["rectified"] for r in per)
     return out

@@ -104,7 +104,8 @@ def test_time_model_fit_and_predict():
 def test_async_dispatch_bit_identical_and_measured():
     """The tentpole's correctness bar, on a forced 4-device CPU mesh:
     with pop sharding off, the async dispatcher's per-graph rewards and
-    whole EA trajectory are BITWISE the serial per-bucket loop's; after
+    whole EA trajectory are BITWISE the serial per-bucket loop's, and a
+    warm generation launches one simulator program per bucket; after
     ``measure()`` the LPT assignment reflects measured per-bucket times
     and the autotuned K builds a working zoo."""
     run_py("""
@@ -131,6 +132,13 @@ for _ in range(3):
 assert np.array_equal(serial.best_reward, asyncd.best_reward)
 for ms, ma in zip(serial.best_mapping, asyncd.best_mapping):
     assert np.array_equal(ms, ma)
+
+# every part's rows share one simulator call per bucket here too
+from repro import obs
+scans = obs.counter("memsim.scan_calls")
+before = scans.value
+asyncd.generation()
+assert scans.value - before == asyncd.zoo.n_buckets, scans.value - before
 
 # measured re-balance: every bucket gets a positive ms, and the new
 # assignment still covers every bucket

@@ -27,6 +27,7 @@ import pytest
 
 import jax
 
+from repro import obs
 from repro.core.egrl import EGRLConfig, ZooEGRL
 from repro.core.sac import SACConfig
 from repro.graphs.graph import WorkloadGraph
@@ -52,16 +53,17 @@ RECORDED = {
 
 # Per-span ceilings of one warm generation.  The real programs: three
 # bucket forwards, the key program and the sampler (rollout.gnn); one
-# Boltzmann and one PG sampler; per part three simulator calls and one
-# zoo-order gather (evaluate); the fitness means; the EA step; the
-# migration.  sac.upload (replay batch copies and noise) is the replay
-# layer's, counted in the total only.
+# Boltzmann and one PG sampler; the join of the parts' rollout rows, one
+# simulator call per bucket over all of them and one zoo-order gather
+# (evaluate); the fitness means; the EA step; the migration.  sac.upload
+# (replay batch copies and noise) is the replay layer's, counted in the
+# total only.
 SPAN_CEILINGS = {"rollout.gnn": 5, "rollout.boltzmann": 1,
-                 "rollout.pg": 1, "evaluate": 12, "fitness": 1,
+                 "rollout.pg": 1, "evaluate": 5, "fitness": 1,
                  "evolve": 1, "migrate": 1, "sac.scan": 1,
                  "generation": 0, "host_sync": 0, "bookkeeping": 0,
                  "replay.insert": 0}
-TOTAL_CEILING = 40
+TOTAL_CEILING = 33
 UPLOAD_CEILING = 6
 
 
@@ -129,32 +131,59 @@ def zoo_run(tmp_path_factory):
             "gen_mean_fitness": [r["gen_mean_fitness"] for r in recs],
         }
         trace_dir = tmp_path_factory.mktemp("trace")
+        scans = obs.counter("memsim.scan_calls")
+        before = scans.value
         jax.profiler.start_trace(str(trace_dir))
         try:
             algo.generation()
         finally:
             jax.profiler.stop_trace()
+        n_scans = scans.value - before
     from jax.profiler import ProfileData
     pb = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
                    recursive=True)
-    return state, launches_by_span(ProfileData.from_file(pb[-1]).planes)
+    return (state, launches_by_span(ProfileData.from_file(pb[-1]).planes),
+            n_scans)
 
 
 def test_zoo_generation_trajectory_is_bitwise_unchanged(zoo_run):
-    state, _ = zoo_run
+    state, _, _ = zoo_run
     assert state == RECORDED
 
 
 def test_zoo_generation_launch_count(zoo_run):
-    _, events = zoo_run
+    _, events, _ = zoo_run
     execs = events[EXECUTE]
     over = {name: (execs[name], cap) for name, cap in SPAN_CEILINGS.items()
             if execs[name] > cap}
     assert not over, f"span: (executions, ceiling) {over}; all {execs}"
     assert execs["rollout.gnn"] >= 3      # the three bucket forwards
-    assert execs["evaluate"] >= 9         # three simulator calls per part
+    assert execs["evaluate"] >= 3         # one simulator call per bucket
     assert sum(execs.values()) <= TOTAL_CEILING, execs
     assert sum(events[UPLOAD].values()) <= UPLOAD_CEILING, events[UPLOAD]
     # only the replay layer's batch copies put anything on the device
     for name in (UPLOAD, PUT):
         assert set(events[name]) <= {"sac.upload"}, (name, events[name])
+
+
+@pytest.mark.parametrize("mode", ["egrl", "ea"])
+def test_warm_generation_scans_each_bucket_once(zoo_run, mode):
+    """Every part's rollout rows share one simulator call per bucket:
+    ``memsim.scan_calls`` grows by the bucket count in a warm
+    generation, not by parts x buckets."""
+    if mode == "egrl":
+        scans = zoo_run[2]
+    else:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("REPRO_GAT_BACKEND", "chunked")
+            cfg = EGRLConfig(pop_size=6, boltzmann_frac=0.34, elites=2,
+                             seed=3)
+            algo = ZooEGRL(_zoo_graphs(), cfg, mode="ea",
+                           fitness_agg="mean", buckets="auto",
+                           pop_shards="off", dispatch="off")
+            algo.generation()
+            counter = obs.counter("memsim.scan_calls")
+            before = counter.value
+            algo.generation()
+            scans = counter.value - before
+    assert scans == 3
