@@ -9,7 +9,7 @@ import time
 
 import numpy as np
 
-from repro.core.egrl import EGRL, EGRLConfig
+from repro.core.egrl import EGRLConfig, ZooEGRL
 from repro.graphs.zoo import PAPER_WORKLOADS
 from repro.memsim.compiler import greedy_dp, compiler_reference
 from repro.memsim.simulator import build_sim_graph, evaluate
@@ -30,11 +30,13 @@ def run_agent(workload: str, agent: str, steps: int, seed: int):
         speedup = float(res["speedup"])
         curve = [(i, r / 5.0) for i, r in history]
     else:
-        algo = EGRL(g, EGRLConfig(total_steps=steps, seed=seed), mode=agent)
+        algo = ZooEGRL([g], EGRLConfig(total_steps=steps, seed=seed),
+                       mode=agent)
         algo.train()
-        speedup = algo.best_reward / algo.cfg.reward_scale \
-            if algo.best_reward > 0 else 0.0
-        curve = [(h["steps"], h["best_speedup"]) for h in algo.history]
+        scale = algo.cfg.reward_scale
+        speedup = max(float(algo.best_reward[0]), 0.0) / scale
+        curve = [(h["steps"], max(h["best_reward_per_graph"][g.name], 0.0)
+                  / scale) for h in algo.history]
     return {"workload": workload, "agent": agent, "seed": seed,
             "steps": steps, "speedup": speedup, "curve": curve,
             "wall_s": round(time.time() - t0, 1)}

@@ -5,13 +5,13 @@ The evaluation leg runs through the bucketed zoo path
 (``evaluate_gnn_zoo``): all destination workloads are stacked into one
 size-bucketed ``BucketedZoo`` (one padded batch per size class, policy
 ``REPRO_ZOO_BUCKETS``) and scored in one device call per bucket per
-trained policy, instead of a per-graph ``evaluate_gnn_on`` loop."""
+trained policy.  Each source trains as a one-graph ``ZooEGRL``."""
 from __future__ import annotations
 
 import json
 import os
 
-from repro.core.egrl import EGRL, EGRLConfig, evaluate_gnn_zoo
+from repro.core.egrl import EGRLConfig, ZooEGRL, evaluate_gnn_zoo
 from repro.graphs.bucketed import build_bucketed_zoo
 from repro.graphs.zoo import PAPER_WORKLOADS
 
@@ -23,11 +23,11 @@ def run(steps: int = 1000, train_on=("bert", "resnet50"),
     batch = build_bucketed_zoo([f() for f in PAPER_WORKLOADS.values()])
     rows = []
     for src in train_on:
-        algo = EGRL(PAPER_WORKLOADS[src](),
-                    EGRLConfig(total_steps=steps, seed=seed), mode="egrl")
+        algo = ZooEGRL([PAPER_WORKLOADS[src]()],
+                       EGRLConfig(total_steps=steps, seed=seed), mode="egrl")
         algo.train()
         vec = algo.best_gnn_vec()
-        src_speedup = algo.best_reward / algo.cfg.reward_scale
+        src_speedup = algo.best_reward[0] / algo.cfg.reward_scale
         zero_shot = evaluate_gnn_zoo(None, vec, seed=seed, batch=batch)
         for dst in PAPER_WORKLOADS:
             # the source graph reports its trained (not zero-shot) speedup

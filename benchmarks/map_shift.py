@@ -7,7 +7,7 @@ import os
 
 import numpy as np
 
-from repro.core.egrl import EGRL, EGRLConfig
+from repro.core.egrl import EGRLConfig, ZooEGRL
 from repro.graphs.zoo import PAPER_WORKLOADS
 from repro.memsim import tiers as T
 from repro.memsim.compiler import compiler_reference
@@ -28,15 +28,16 @@ def run(steps: int = 1000, workloads=("resnet50",), seed: int = 0,
     for w in workloads:
         g = PAPER_WORKLOADS[w]()
         cmap, _ = compiler_reference(g)
-        algo = EGRL(g, EGRLConfig(total_steps=steps, seed=seed))
+        algo = ZooEGRL([g], EGRLConfig(total_steps=steps, seed=seed),
+                       mode="egrl")
         algo.train()
-        emap = algo.best_mapping
+        emap = algo.best_mapping[0]
         wb = np.array([nd.weight_bytes for nd in g.nodes])
         ab = np.array([nd.ofm_bytes for nd in g.nodes])
         tw = transition_matrix(cmap[:, 0], emap[:, 0], wb)
         ta = transition_matrix(cmap[:, 1], emap[:, 1], ab)
         out[w] = {"weights": tw.tolist(), "acts": ta.tolist(),
-                  "speedup": algo.best_reward / algo.cfg.reward_scale}
+                  "speedup": algo.best_reward[0] / algo.cfg.reward_scale}
         if log:
             names = [t.name for t in T.TIERS]
             log(f"fig7,{w},speedup,{out[w]['speedup']:.3f}")

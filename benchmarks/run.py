@@ -198,7 +198,7 @@ def bench_zoo_eval() -> None:
 def bench_generation() -> None:
     """Inner-loop gate: ms per EGRL generation (pop 20), EA-only (the
     device-resident EA path) and full EGRL (adds SAC updates)."""
-    from repro.core.egrl import EGRL, EGRLConfig
+    from repro.core.egrl import EGRLConfig, ZooEGRL
     from repro.graphs.zoo import resnet50, bert
 
     reps = max(3, min(10, STEPS // 80))
@@ -207,7 +207,7 @@ def bench_generation() -> None:
         g = gf()
         row = {}
         for mode in ("ea", "egrl"):
-            algo = EGRL(g, EGRLConfig(seed=0), mode=mode)
+            algo = ZooEGRL([g], EGRLConfig(seed=0), mode=mode)
             for _ in range(2):
                 algo.generation()          # compile + warmup
             t0 = time.perf_counter()
@@ -318,14 +318,14 @@ def _pop_sharding_child() -> None:
     machine-readable line.  Runs in a subprocess because the host device
     count (XLA_FLAGS) is fixed at first jax init."""
     import jax
-    from repro.core.egrl import EGRL, EGRLConfig
+    from repro.core.egrl import EGRLConfig, ZooEGRL
     from repro.graphs.zoo import resnet50
 
     n_dev = len(jax.devices())
     reps = max(3, min(10, STEPS // 80))
     # pop 64 split 48/16 so every mesh size in (1, 2, 4) divides both
     cfg = EGRLConfig(pop_size=64, boltzmann_frac=0.25, elites=8, seed=0)
-    algo = EGRL(resnet50(), cfg, mode="ea", pop_shards=n_dev)
+    algo = ZooEGRL([resnet50()], cfg, mode="ea", pop_shards=n_dev)
     for _ in range(2):
         algo.generation()              # compile + warmup
     t0 = time.perf_counter()
@@ -380,7 +380,8 @@ def _bucket_dispatch_child() -> None:
     import numpy as np
 
     import jax
-    from repro.core.egrl import _SAMPLE_ACTIONS, EGRLConfig, ZooEGRL
+    from repro.core import gnn
+    from repro.core.egrl import EGRLConfig, ZooEGRL
     from repro.distributed.dispatch import autotune_bucket_k
     from repro.graphs.bucketed import bucket_keys_batch
     from repro.graphs.zoo import WORKLOADS, bert, resnet50, tiny_gpt
@@ -426,6 +427,7 @@ def _bucket_dispatch_child() -> None:
     keys = jax.random.split(jax.random.PRNGKey(1), pop.shape[0])
 
     bkeys = bucket_keys_batch(keys, serial.zoo.n_buckets)
+    sample = jax.jit(jax.vmap(gnn.sample_actions))
 
     def async_pipe():
         lg = dsp.forward(pop)
@@ -434,7 +436,7 @@ def _bucket_dispatch_child() -> None:
 
     def serial_pipe():
         lgs = [f(serial.gnn_pop) for f in serial._pop_logits]
-        maps = tuple(_SAMPLE_ACTIONS(kc, lg) for kc, lg in zip(bkeys, lgs))
+        maps = tuple(sample(kc, lg) for kc, lg in zip(bkeys, lgs))
         jax.block_until_ready(evaluate_population_bucketed(
             serial.zoo, maps, cfg.reward_scale)["reward"])
 

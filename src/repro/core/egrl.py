@@ -1,37 +1,32 @@
-"""EGRL driver (Algorithm 2): mixed EA population (GNN + Boltzmann) and a
-SAC learner sharing one replay buffer, with PG->EA migration and
-GNN->Boltzmann prior seeding.
+"""EGRL driver (Algorithm 2): ``ZooEGRL``, a mixed EA population (GNN +
+Boltzmann genomes) and a SAC learner sharing one replay bank, with
+PG->EA migration and GNN->Boltzmann prior seeding, trained against a
+workload zoo.  One workload is the one-graph zoo:
+``ZooEGRL([graph], cfg, mode="egrl")``.
 
 Device-resident generation (beyond-paper optimization): the population
 is stored as stacked arrays — GNN genomes as one (n_g, V) flat-parameter
 matrix, Boltzmann genomes as one (n_b, F) flat matrix — and a generation
 is a series of jitted device calls:
 
-1. ONE vmapped GNN forward over the stacked parameter matrix (one per
-   size bucket in the zoo driver),
+1. ONE vmapped GNN forward over the stacked parameter matrix per size
+   bucket,
 2. ONE vmapped Boltzmann sample (+ one batched PG rollout sample),
-3. one vmapped simulator call per population part in the per-graph
-   driver (memsim.simulator; GNN / Boltzmann / PG mappings are scored
-   separately so the sharded parts keep their ("pop",) placement — see
-   generation()); the zoo driver joins the parts' rows and scores them
-   in one simulator call per size bucket,
+3. one simulator call per size bucket over the rows of every
+   population part (GNN, Boltzmann, PG) joined,
 4. ONE jitted EA step (core/ea.py: tournament, crossover, seeding,
    mutation over the stacked genomes) plus a jitted migration row
    write for the PG policy.
 
-The zoo driver runs the glue between those programs (every key split of
-the generation, the samplers' bucket slices, the EA step's seeding
-grid, the zoo-order gathers, the fitness means, the actor flatten of
-the migration, the join of the parts' rollout rows) inside a few more
-jitted programs: a warm 3-bucket egrl generation launches 29 programs,
-13 of them the SAC learner's batch upload
-(tests/test_generation_launches.py), where the eager glue made 114.
+The glue between those programs (every key split of the generation,
+the samplers' bucket slices, the EA step's seeding grid, the zoo-order
+gathers, the fitness means, the actor flatten of the migration, the
+join of the parts' rollout rows) runs inside a few more jitted
+programs: a warm 3-bucket egrl generation launches 29 programs, 13 of
+them the SAC learner's batch upload (tests/test_generation_launches.py).
 Results come back to the host in one blocking read per result array (9
 per 3-bucket egrl generation, counted by the ``egrl.device_reads``
-counter), for the replay buffer, best-mapping tracking and logging.
-The seed implementation instead kept a Python list of per-individual
-genomes: building each child ran 1-3 host RNG ops plus device
-transfers, serializing the inner loop.
+counter), for the replay bank, best-mapping tracking and logging.
 
 Population sharding (PR 2, padding PR 3): when more than one device is
 visible (see repro.distributed.population for the REPRO_POP_SHARDS
@@ -53,22 +48,18 @@ pure capacity/throughput knob, not a different algorithm.
 Modes: "egrl" (full), "ea" (ablate PG), "pg" (ablate EA) — the paper's
 baseline agents.
 
-Multi-workload training (PR 3, PG member PR 4, size buckets PR 5):
-``ZooEGRL`` evolves ONE population against a whole workload zoo — the
-graphs live in a size-bucketed ``BucketedZoo`` (one ``GraphBatch`` per
-size class, policy ``REPRO_ZOO_BUCKETS``), per-generation fitness is a
-selectable aggregate (mean / worst-case, ``REPRO_FITNESS_AGG``) of
-per-graph rewards, evaluated in one jitted device call PER BUCKET
+The zoo (PR 3, PG member PR 4, size buckets PR 5) lives in a
+size-bucketed ``BucketedZoo`` (one ``GraphBatch`` per size class, policy
+``REPRO_ZOO_BUCKETS``); per-generation fitness is a selectable
+aggregate (mean / worst-case, ``REPRO_FITNESS_AGG``) of per-graph
+rewards, evaluated in one jitted device call PER BUCKET
 (memsim.batch.evaluate_population_bucketed) so small workloads don't
-pay the biggest graph's padded scan.  GNN genomes transfer unchanged
-(their parameters are graph-size independent); Boltzmann genomes span
+pay the biggest graph's padded scan.  GNN genomes are graph-size
+independent, so they transfer between graphs; Boltzmann genomes span
 the bucket-major padded node grid ``sum_k(G_k · N_max_k)``.  In "egrl"
-mode the population is seeded by ``ZooSAC`` — the batched
-multi-workload SAC learner (core/sac.py) trained from a per-zoo-index
-``ReplayBank`` — with the same PG->EA migration as the per-graph
-driver, so the zoo path runs the full hybrid of the paper instead of
-the EA-only ablation.  Single-bucket zoos are bit-identical to the
-flat GraphBatch path (see graphs/bucketed.py's PRNG discipline).
+mode the PG member is ``ZooSAC`` (core/sac.py), trained from a
+per-zoo-index ``ReplayBank``.  Single-bucket zoos are bit-identical to
+the flat GraphBatch path (see graphs/bucketed.py's PRNG discipline).
 """
 from __future__ import annotations
 
@@ -85,8 +76,8 @@ from repro import obs
 from repro.core import boltzmann as bz
 from repro.core import ea as ea_mod
 from repro.core import gnn
-from repro.core.replay import ReplayBank, ReplayBuffer
-from repro.core.sac import SACConfig, SACLearner, ZooSAC
+from repro.core.replay import ReplayBank
+from repro.core.sac import SACConfig, ZooSAC
 from repro.distributed.dispatch import BucketDispatcher
 from repro.distributed.population import resolve_pop_sharding
 from repro.graphs.batch import GraphBatch
@@ -95,8 +86,6 @@ from repro.graphs.bucketed import (BucketedZoo, bucket_keys_batch,
 from repro.graphs.graph import WorkloadGraph
 from repro.memsim.batch import (aggregate_rewards,
                                 evaluate_population_bucketed)
-from repro.memsim.compiler import compiler_reference
-from repro.memsim.simulator import build_sim_graph, evaluate_population
 from repro.utils.envpolicy import env_policy
 
 
@@ -147,10 +136,8 @@ def _evolve_with_fitness_mask(evolve_fn, n_g, n_g_pad, n_b, n_b_pad,
 # paths keep per-instance closures: their mesh / out_shardings are
 # instance state (and multi-device runs amortize compiles anyway).
 
-_POP_LOGITS = jax.jit(gnn.population_logits, static_argnames=("backend",))
 _POP_LOGITS_ZOO = jax.jit(gnn.population_logits_zoo,
                           static_argnames=("backend",))
-_SAMPLE_ACTIONS = jax.jit(jax.vmap(gnn.sample_actions))
 
 
 @partial(jax.jit, static_argnames=("idx",))
@@ -304,155 +291,6 @@ def _evolve_program(n_g, n_g_pad, n_b, n_b_pad, n_nodes, e_g, e_b,
         tournament_k=tournament_k)
 
 
-class _EvoPopulation:
-    """Shared population scaffolding for the per-graph ``EGRL`` and the
-    multi-workload ``ZooEGRL``: the fixed-slot population split + elite
-    formulas, stacked-genome init, sharded/padded placement, and the
-    jitted evolve wiring.  Keeping this in ONE place means a fix to
-    e.g. the padding discipline applies to both drivers.
-
-    The subclass must set ``self.cfg``, ``self.mode``, ``self.key`` and
-    ``self._template`` before calling ``_init_populations`` — note the
-    PRNG contract: EGRL's template is the SAC actor (no key consumed),
-    ZooEGRL draws one key for its template first.
-    """
-
-    def _k(self):
-        self.key, k = jax.random.split(self.key)
-        return k
-
-    def _split_population(self):
-        """Fixed encoding slots (see core/ea.py): n_b Boltzmann + n_g
-        GNN genomes whose counts never change; elites split
-        proportionally."""
-        cfg = self.cfg
-        if self.mode == "pg":
-            self.n_g = self.n_b = 0
-        else:
-            self.n_b = max(1, int(round(cfg.pop_size * cfg.boltzmann_frac)))
-            self.n_g = cfg.pop_size - self.n_b
-        self.e_g = min(self.n_g, max(1, round(
-            cfg.elites * self.n_g / max(cfg.pop_size, 1)))) if self.n_g else 0
-        self.e_b = min(self.n_b, max(0, cfg.elites - self.e_g))
-
-    def _init_populations(self, n_features: int, bz_nodes: int, pop_shards):
-        """Stacked genome arrays (GNN: (n_g, V) flat params; Boltzmann:
-        (n_b, F) flats over ``bz_nodes`` node slots), their placement —
-        single device, or row-sharded over a ("pop",) mesh per the
-        repro.distributed.population policy — and the jitted evolve
-        call.  A shard count that does not divide a sub-population is
-        handled by padding with masked rows: zero genomes whose fitness
-        the evolve wrapper pins to -inf, invisible to the real-row
-        trajectory."""
-        cfg = self.cfg
-        vec0 = gnn.flatten_params(self._template)
-        self.gnn_pop = (jnp.stack([
-            gnn.flatten_params(gnn.init_gnn(self._k(), n_features))
-            for _ in range(self.n_g)]) if self.n_g
-            else jnp.zeros((0, vec0.shape[0])))
-        self.bz_pop = (jnp.stack([
-            bz.to_flat(*bz.init_boltzmann(self._k(), bz_nodes))
-            for _ in range(self.n_b)]) if self.n_b
-            else jnp.zeros((0, bz.flat_size(bz_nodes))))
-
-        self.pop_sharding = resolve_pop_sharding(
-            self.n_g, self.n_b, pop_shards)
-        self.n_g_pad, self.n_b_pad = self.pop_sharding.padded(
-            self.n_g, self.n_b)
-        self.gnn_pop = self.pop_sharding.put(
-            _pad_rows(self.gnn_pop, self.n_g_pad))
-        self.bz_pop = self.pop_sharding.put(
-            _pad_rows(self.bz_pop, self.n_b_pad))
-
-        if self.pop_sharding.active:
-            # sharded paths stay per-instance: mesh/out_shardings are
-            # instance state (see the module-level program comment)
-            base_evolve = partial(
-                ea_mod.evolve_sharded, self.pop_sharding.mesh,
-                n_nodes=bz_nodes, e_g=self.e_g, e_b=self.e_b, n_g=self.n_g,
-                n_b=self.n_b, tournament_k=cfg.tournament_k,
-                crossover_prob=cfg.crossover_prob, mut_prob=cfg.mut_prob,
-                mut_frac=cfg.mut_frac, mut_std=cfg.mut_std)
-            self._evolve = jax.jit(partial(
-                _evolve_with_fitness_mask, base_evolve,
-                self.n_g, self.n_g_pad, self.n_b, self.n_b_pad))
-            # PG migration: jitted flatten + row write into the last
-            # REAL GNN slot, landing back in the population sharding (a
-            # collective scatter, not a host copy).  Takes the actor
-            # itself or its flat (V,) genome.  Shared by EGRL and
-            # ZooEGRL — both learners' actors flatten to the same (V,)
-            # genome encoding (GNN parameters are graph-size
-            # independent).
-            self._migrate = jax.jit(
-                lambda pop, params: pop.at[self.n_g - 1].set(
-                    gnn.flatten_params(params)),
-                out_shardings=self.pop_sharding.sharding)
-        else:
-            self._evolve = _evolve_program(
-                self.n_g, self.n_g_pad, self.n_b, self.n_b_pad,
-                bz_nodes, self.e_g, self.e_b, cfg.tournament_k,
-                cfg.crossover_prob, cfg.mut_prob, cfg.mut_frac,
-                cfg.mut_std)
-            self._migrate = lambda pop, params: _migrate_row(
-                pop, params, idx=self.n_g - 1)
-
-    # ------------------------------------------------------- warm start
-    def _prior_logits(self, vec: jnp.ndarray) -> jnp.ndarray:
-        """Posterior logits of the flat GNN params ``vec`` over this
-        driver's Boltzmann node grid (subclass hook: (N, 2, 3) for the
-        per-graph driver, the bucket-major (n_eff, 2, 3) grid for the
-        zoo driver)."""
-        raise NotImplementedError
-
-    def prior_logits(self, vec) -> jnp.ndarray:
-        """Public wrapper over the driver's Boltzmann-grid posterior
-        logits for flat GNN params ``vec`` — the placement service
-        blends these with neighbor-mapping one-hots before passing the
-        result back through ``warm_start(logits=...)``."""
-        return self._prior_logits(jnp.asarray(vec, jnp.float32))
-
-    def warm_start(self, vec, *, gnn_frac: float = 0.5,
-                   noise_std: float = 0.05, t_init: float = 0.5,
-                   logits=None):
-        """Seed the population from a trained policy's flat GNN params
-        (zero-shot warm start — how the placement service turns its
-        accumulated prior into a head start for each miss batch's
-        refinement).  GNN row 0 becomes the prior EXACTLY (so one elite
-        generation preserves it verbatim), the next ``gnn_frac`` of the
-        sub-population noisy copies, the rest keep their random init
-        for diversity; EVERY Boltzmann genome is re-seeded from the
-        prior's posterior logits (Algorithm 2's GNN->Boltzmann seeding,
-        applied at init time via ``bz.seed_from_logits``).  Draws from
-        the driver's key stream, so warm-started trajectories are
-        deterministic per (cfg.seed, call order); padded sharding rows
-        stay untouched and the result is re-placed in the population
-        sharding.
-
-        ``logits`` (optional, the driver's Boltzmann node grid shape —
-        see ``_prior_logits``) overrides the prior's posterior logits
-        for the Boltzmann re-seeding: the placement service passes a
-        blend of the GNN prior's logits and one-hot logits derived from
-        a nearest-neighbor's committed MAPPING, so a near-identical
-        graph's refinement starts from its neighbor's answer instead of
-        the prior alone.  The GNN rows still seed from ``vec``."""
-        vec = jnp.asarray(vec, jnp.float32)
-        if self.n_g:
-            n_seed = max(1, int(round(gnn_frac * self.n_g)))
-            rows = [vec] + [
-                vec + noise_std * jax.random.normal(self._k(), vec.shape)
-                for _ in range(n_seed - 1)]
-            self.gnn_pop = self.pop_sharding.put(jnp.concatenate(
-                [jnp.stack(rows), self.gnn_pop[n_seed:]]))
-        if self.n_b:
-            logits = (self._prior_logits(vec) if logits is None
-                      else jnp.asarray(logits, jnp.float32))
-            seeds = [bz.seed_from_logits(logits, self._k(), t_init)
-                     for _ in range(self.n_b)]
-            rows = [bz.to_flat(b.prior, b.log_t) for b in seeds]
-            self.bz_pop = self.pop_sharding.put(jnp.concatenate(
-                [jnp.stack(rows), self.bz_pop[self.n_b:]]))
-
-
 @dataclasses.dataclass
 class EGRLConfig:
     pop_size: int = 20
@@ -471,204 +309,7 @@ class EGRLConfig:
     sac: SACConfig = dataclasses.field(default_factory=SACConfig)
 
 
-class EGRL(_EvoPopulation):
-    def __init__(self, graph: WorkloadGraph, cfg: EGRLConfig = EGRLConfig(),
-                 mode: str = "egrl", pop_shards=None):
-        """``pop_shards`` overrides the REPRO_POP_SHARDS policy (int,
-        "auto", or "off"); default: resolve from the environment."""
-        assert mode in ("egrl", "ea", "pg")
-        self.g = graph
-        self.cfg = cfg
-        self.mode = mode
-        self.key = jax.random.PRNGKey(cfg.seed)
-
-        self.feats = jnp.asarray(graph.features())
-        self.adj = jnp.asarray(graph.adjacency())
-        self.sg = build_sim_graph(graph)
-        _, self.ref_latency = compiler_reference(graph)
-        self.ref_latency = jnp.float32(self.ref_latency)
-
-        self.learner = SACLearner(self.feats, self.adj, self._k(), cfg.sac)
-        self.buffer = ReplayBuffer(graph.n, seed=cfg.seed)
-        self._template = self.learner.actor
-
-        # ---- stacked populations + placement + evolve (_EvoPopulation)
-        self._split_population()
-        self._init_populations(self.feats.shape[1], graph.n, pop_shards)
-
-        # ---- vmapped population programs: bound module-level jits, so
-        # a second EGRL on the same graph geometry reuses the compiled
-        # executables; a sharded population runs its forward shard by
-        # shard (``PopSharding.map_rows``), single rows on one device
-        self._row_gnn_logits = partial(
-            _POP_LOGITS, self._template, self.feats, self.adj)
-        self._pop_gnn_logits = partial(
-            self.pop_sharding.map_rows(gnn.population_logits)
-            if self.pop_sharding.active else _POP_LOGITS,
-            self._template, self.feats, self.adj)
-        self._pop_sample = _SAMPLE_ACTIONS
-        self._pop_boltz = _bz_sample_pop
-
-        self.steps = 0
-        self.best_reward = -np.inf
-        self.best_mapping: Optional[np.ndarray] = None
-        self.history: List[Dict] = []
-
-    # --------------------------------------------------------- generation
-    def generation(self) -> Dict:
-        # span timing note: jax dispatch is async, so the rollout /
-        # evolve child spans measure DISPATCH (+ compile on a first
-        # call, split out as jit_compile by _compile_tracked); the
-        # device waits land in the device_read spans (host_sync's
-        # result reads, sac.read's loss reads), which instrumentation
-        # neither adds nor moves.
-        with obs.span("generation", driver="egrl", mode=self.mode) as sp:
-            return self._generation(sp)
-
-    def _generation(self, sp) -> Dict:
-        cfg = self.cfg
-        n_g, n_b = self.n_g, self.n_b
-
-        # ---- rollouts: stacked device calls, nothing leaves the device.
-        # Each part (GNN pop, Boltzmann pop, PG rollouts) is evaluated
-        # separately: concatenating the pop-sharded population samples
-        # with the single-device PG mappings would resolve the result to
-        # fully-replicated and throw away the ("pop",) sharding, so the
-        # per-part calls keep evaluation shard-local AND hand the EA its
-        # fitness vectors without slicing a mixed array.  Per-mapping
-        # math is row-independent, so the rewards are bitwise the same
-        # as one fused call.
-        parts, results = {}, {}
-        # rows beyond these are masked padding slots (divisible sharding)
-        real = {"g": n_g, "b": n_b}
-        logits_g = None
-        if n_g:
-            with obs.span("rollout.gnn", rows=n_g):
-                logits_g = self._pop_gnn_logits(self.gnn_pop)
-                # keys are split with the REAL count (split(k, n) has
-                # no prefix property) and repeated into the padding rows
-                parts["g"] = self._pop_sample(_pad_keys(
-                    jax.random.split(self._k(), n_g), self.n_g_pad),
-                    logits_g)
-        if n_b:
-            with obs.span("rollout.boltzmann", rows=n_b):
-                parts["b"] = self._pop_boltz(_pad_keys(
-                    jax.random.split(self._k(), n_b), self.n_b_pad),
-                    self.bz_pop)
-        if self.mode != "ea":
-            with obs.span("rollout.pg", rows=cfg.pg_rollouts):
-                parts["pg"] = self.learner.explore_actions(cfg.pg_rollouts)
-        with obs.span("evaluate", parts=len(parts)):
-            for name, maps in parts.items():
-                results[name] = evaluate_population(
-                    self.sg, maps, self.ref_latency, cfg.reward_scale)
-
-        # ---- EA step (Algorithm 2 lines 8-25), still on device
-        if n_g or n_b:
-            with obs.span("evolve"):
-                empty = jnp.zeros((0,), jnp.float32)
-                self.gnn_pop, self.bz_pop = self._evolve(
-                    self._k(),
-                    self.gnn_pop,
-                    results["g"]["reward"] if n_g else empty,
-                    self.bz_pop,
-                    results["b"]["reward"] if n_b else empty,
-                    logits_g if logits_g is not None
-                    else jnp.zeros((0, self.g.n, 2, 3)))
-
-        # ---- host sync: one blocking read per result array, for the
-        # buffer + logging (padding rows are sliced away — they never
-        # hit the buffer, the step count or the best-mapping tracking)
-        def np_real(name, x):
-            a = obs.device_read(np.asarray, x)
-            return a[:real[name]] if name in real else a
-
-        with obs.span("host_sync"):
-            per_part = {n: np_real(n, results[n]["reward"])
-                        for n in parts}
-            rewards = np.concatenate(list(per_part.values()))
-            maps_np = np.concatenate(
-                [np_real(n, m) for n, m in parts.items()])
-            valid = np.concatenate(
-                [np_real(n, results[n]["valid"]) for n in parts])
-        with obs.span("replay.insert"):
-            self.buffer.add_batch(maps_np, rewards)
-        with obs.span("bookkeeping"):
-            self.steps += len(maps_np)
-            gen_best = int(np.argmax(rewards))
-            if rewards[gen_best] > self.best_reward:
-                self.best_reward = float(rewards[gen_best])
-                self.best_mapping = maps_np[gen_best].copy()
-
-        # ---- PG updates: one gradient step per env step this generation
-        info = {}
-        if self.mode != "ea":
-            info = self.learner.update(self.buffer, len(maps_np))
-            # ---- migration: PG weights into the last GNN slot, the
-            # lowest-ranked child (Algorithm 2's replace-weakest: in the
-            # seed code fresh children carried -inf fitness, so argmin
-            # always picked a child, never an elite).  When every GNN
-            # slot is an elite (n_g == e_g) skip, preserving elitism.
-            if self.mode == "egrl" and n_g > self.e_g:
-                obs.counter("egrl.migrations").inc()
-                with obs.span("migrate"):
-                    self.gnn_pop = self._migrate(self.gnn_pop,
-                                                 self.learner.actor)
-        obs.gauge("egrl.replay_occupancy").set(len(self.buffer))
-
-        rec = {
-            "steps": self.steps,
-            "gen_best_reward": float(rewards.max()),
-            "gen_mean_reward": float(rewards.mean()),
-            "best_reward": self.best_reward,
-            "best_speedup": self.best_reward / cfg.reward_scale
-            if self.best_reward > 0 else 0.0,
-            "valid_frac": float(valid.mean()),
-            **info,
-        }
-        # per-member-type attribution from the host copies the loop
-        # already made — no extra device fetch
-        sp.set(steps=self.steps, gen_best=rec["gen_best_reward"],
-               gen_mean=rec["gen_mean_reward"], best=self.best_reward,
-               valid_frac=rec["valid_frac"],
-               **{f"best_{n}": float(v.max())
-                  for n, v in per_part.items() if v.size})
-        self.history.append(rec)
-        return rec
-
-    def train(self, total_steps: Optional[int] = None, log=None):
-        total = total_steps or self.cfg.total_steps
-        while self.steps < total:
-            rec = self.generation()
-            if log and len(self.history) % 10 == 1:
-                log(f"[{self.mode}] steps {rec['steps']:5d} "
-                    f"best speedup {rec['best_speedup']:.3f} "
-                    f"valid {rec['valid_frac']:.2f}")
-        return self.history
-
-    # ----------------------------------------------------- deployment API
-    def _prior_logits(self, vec):
-        return self._row_gnn_logits(vec[None])[0]
-
-    def best_policy_logits(self):
-        """Logits of the top-ranked policy in the population (deployment):
-        the best GNN, else the SAC actor, else the best Boltzmann prior
-        (Boltzmann-only "ea" ablation — crashed in the seed code)."""
-        if self.n_g:
-            return self._prior_logits(jnp.asarray(self.best_gnn_vec()))
-        if self.mode != "ea":
-            return self.learner.policy_logits()
-        return bz.boltzmann_logits(bz.from_flat(self.bz_pop[0], self.g.n))
-
-    def best_gnn_vec(self) -> Optional[np.ndarray]:
-        """Flat params of the best GNN (row 0 is the top elite after a
-        generation; before any generation, an arbitrary init member)."""
-        if self.n_g:
-            return np.asarray(self.gnn_pop[0])
-        return np.asarray(gnn.flatten_params(self.learner.actor))
-
-
-class ZooEGRL(_EvoPopulation):
+class ZooEGRL:
     """Multi-workload EGRL: one EA population trained against the whole
     workload zoo, every generation scored in one jitted device call PER
     SIZE BUCKET.
@@ -686,28 +327,28 @@ class ZooEGRL(_EvoPopulation):
     - ``"worst"`` — minimax: the weakest graph's reward, so evolution
       cannot trade one workload off against another.
 
-    GNN genomes are the same (V,) flat parameter vectors as the
-    per-graph ``EGRL`` (Graph U-Net weights are graph-size independent;
-    the per-bucket forwards mask padding, see core.gnn), so populations
-    transfer between per-graph and zoo training — and between bucketing
-    policies.  Boltzmann genomes span the bucket-major padded node grid
-    ``n_eff = sum_k(G_k * N_max_k)`` — one prior/temperature table per
-    (graph, node) slot — reusing the flat encoding with ``n_nodes =
-    n_eff``; for a single-bucket zoo this is exactly the flat G · N_max
-    grid, and ALL single-bucket trajectories are bit-identical to the
-    flat-GraphBatch path (per-bucket PRNG keys come from
-    ``bucket_keys``, which consumes the caller's key unchanged at K=1).
+    GNN genomes are (V,) flat parameter vectors (Graph U-Net weights
+    are graph-size independent; the per-bucket forwards mask padding,
+    see core.gnn), so populations transfer between zoos and between
+    bucketing policies.  Boltzmann genomes span the bucket-major padded
+    node grid ``n_eff = sum_k(G_k * N_max_k)`` — one prior/temperature
+    table per (graph, node) slot — reusing the flat encoding with
+    ``n_nodes = n_eff``; for a single-bucket zoo this is exactly the
+    flat G · N_max grid, and ALL single-bucket trajectories are
+    bit-identical to the flat-GraphBatch path (per-bucket PRNG keys come
+    from ``bucket_keys``, which consumes the caller's key unchanged at
+    K=1).  A one-graph zoo (``ZooEGRL([graph], ...)``) is the per-graph
+    optimizer: one bucket at the graph's own width.
 
-    Modes mirror the per-graph driver: "egrl" (full hybrid — the
-    ``ZooSAC`` learner contributes ``pg_rollouts`` zoo-wide exploration
-    rows, trains from the per-zoo-index ``ReplayBank`` with one batched
-    gradient step per rollout row, and migrates its actor into the last
-    real GNN slot), "ea" (ablate PG — no learner, no bank) and "pg"
-    (ablate EA).  Composes with the ("pop",) population sharding
-    exactly like ``EGRL`` — every per-bucket call is still a pure vmap
-    over the population axis, the EA step handles padded slots, and
-    migration is a jitted row write with ``out_shardings`` pinned to
-    the population sharding.
+    Modes: "egrl" (full hybrid — the ``ZooSAC`` learner contributes
+    ``pg_rollouts`` zoo-wide exploration rows, trains from the
+    per-zoo-index ``ReplayBank`` with one batched gradient step per
+    rollout row, and migrates its actor into the last real GNN slot),
+    "ea" (ablate PG — no learner, no bank) and "pg" (ablate EA).
+    Composes with the ("pop",) population sharding: every per-bucket
+    call is still a pure vmap over the population axis, the EA step
+    handles padded slots, and migration is a jitted row write with
+    ``out_shardings`` pinned to the population sharding.
 
     A generation's rollout rows — the GNN part, the Boltzmann part and
     the PG rows, with their padding rows — are joined along the row axis
@@ -753,12 +394,12 @@ class ZooEGRL(_EvoPopulation):
             self.learner, self.bank = None, None
             self._template = gnn.init_gnn(self._k(), n_features)
         else:
-            # mirror EGRL: the learner key is drawn first and the SAC
-            # actor doubles as the population template
+            # the learner key is drawn first and the SAC actor doubles
+            # as the population template
             self.learner = ZooSAC(self.zoo, self._k(), cfg.sac)
             self.bank = ReplayBank(self.zoo.node_slots, seed=cfg.seed)
             self._template = self.learner.actor
-        # ---- stacked populations + placement + evolve (_EvoPopulation)
+        # ---- stacked populations + placement + evolve
         self._split_population()
         self._init_populations(n_features, self.n_eff, pop_shards)
 
@@ -844,8 +485,89 @@ class ZooEGRL(_EvoPopulation):
         self.best_fitness = -np.inf
         self.history: List[Dict] = []
 
+    def _k(self):
+        self.key, k = jax.random.split(self.key)
+        return k
+
+    def _split_population(self):
+        """Fixed encoding slots (see core/ea.py): n_b Boltzmann + n_g
+        GNN genomes whose counts never change; elites split
+        proportionally."""
+        cfg = self.cfg
+        if self.mode == "pg":
+            self.n_g = self.n_b = 0
+        else:
+            self.n_b = max(1, int(round(cfg.pop_size * cfg.boltzmann_frac)))
+            self.n_g = cfg.pop_size - self.n_b
+        self.e_g = min(self.n_g, max(1, round(
+            cfg.elites * self.n_g / max(cfg.pop_size, 1)))) if self.n_g else 0
+        self.e_b = min(self.n_b, max(0, cfg.elites - self.e_g))
+
+    def _init_populations(self, n_features: int, bz_nodes: int, pop_shards):
+        """Stacked genome arrays (GNN: (n_g, V) flat params; Boltzmann:
+        (n_b, F) flats over ``bz_nodes`` node slots), their placement —
+        single device, or row-sharded over a ("pop",) mesh per the
+        repro.distributed.population policy — and the jitted evolve
+        call.  A shard count that does not divide a sub-population is
+        handled by padding with masked rows: zero genomes whose fitness
+        the evolve wrapper pins to -inf, invisible to the real-row
+        trajectory."""
+        cfg = self.cfg
+        vec0 = gnn.flatten_params(self._template)
+        self.gnn_pop = (jnp.stack([
+            gnn.flatten_params(gnn.init_gnn(self._k(), n_features))
+            for _ in range(self.n_g)]) if self.n_g
+            else jnp.zeros((0, vec0.shape[0])))
+        self.bz_pop = (jnp.stack([
+            bz.to_flat(*bz.init_boltzmann(self._k(), bz_nodes))
+            for _ in range(self.n_b)]) if self.n_b
+            else jnp.zeros((0, bz.flat_size(bz_nodes))))
+
+        self.pop_sharding = resolve_pop_sharding(
+            self.n_g, self.n_b, pop_shards)
+        self.n_g_pad, self.n_b_pad = self.pop_sharding.padded(
+            self.n_g, self.n_b)
+        self.gnn_pop = self.pop_sharding.put(
+            _pad_rows(self.gnn_pop, self.n_g_pad))
+        self.bz_pop = self.pop_sharding.put(
+            _pad_rows(self.bz_pop, self.n_b_pad))
+
+        if self.pop_sharding.active:
+            # sharded paths stay per-instance: mesh/out_shardings are
+            # instance state (see the module-level program comment)
+            base_evolve = partial(
+                ea_mod.evolve_sharded, self.pop_sharding.mesh,
+                n_nodes=bz_nodes, e_g=self.e_g, e_b=self.e_b, n_g=self.n_g,
+                n_b=self.n_b, tournament_k=cfg.tournament_k,
+                crossover_prob=cfg.crossover_prob, mut_prob=cfg.mut_prob,
+                mut_frac=cfg.mut_frac, mut_std=cfg.mut_std)
+            self._evolve = jax.jit(partial(
+                _evolve_with_fitness_mask, base_evolve,
+                self.n_g, self.n_g_pad, self.n_b, self.n_b_pad))
+            # PG migration: jitted flatten + row write into the last
+            # REAL GNN slot, landing back in the population sharding (a
+            # collective scatter, not a host copy).  Takes the actor
+            # itself or its flat (V,) genome.
+            self._migrate = jax.jit(
+                lambda pop, params: pop.at[self.n_g - 1].set(
+                    gnn.flatten_params(params)),
+                out_shardings=self.pop_sharding.sharding)
+        else:
+            self._evolve = _evolve_program(
+                self.n_g, self.n_g_pad, self.n_b, self.n_b_pad,
+                bz_nodes, self.e_g, self.e_b, cfg.tournament_k,
+                cfg.crossover_prob, cfg.mut_prob, cfg.mut_frac,
+                cfg.mut_std)
+            self._migrate = lambda pop, params: _migrate_row(
+                pop, params, idx=self.n_g - 1)
+
     def generation(self) -> Dict:
-        # same dispatch-vs-sync span semantics as EGRL.generation
+        # span timing note: jax dispatch is async, so the rollout /
+        # evolve child spans measure DISPATCH (+ compile on a first
+        # call, split out as jit_compile by _compile_tracked); the
+        # device waits land in the device_read spans (host_sync's
+        # result reads, sac.read's loss reads), which instrumentation
+        # neither adds nor moves.
         with obs.span("generation", driver="zoo", mode=self.mode) as sp:
             return self._generation(sp)
 
@@ -959,8 +681,8 @@ class ZooEGRL(_EvoPopulation):
 
         # ---- PG member: bank insert, one batched zoo-wide gradient
         # step per rollout row (the update scan consumes a per-bucket
-        # (G_k, B) batch per step, so this matches EGRL's
-        # one-step-per-env-step budget at the row level), then
+        # (G_k, B) batch per step: one step per env step at the row
+        # level), then
         # migration into the last real GNN slot
         info = {}
         if self.mode != "ea":
@@ -1008,40 +730,69 @@ class ZooEGRL(_EvoPopulation):
                     f"valid {rec['valid_frac']:.2f}")
         return self.history
 
-    def _prior_logits(self, vec):
-        # bucket-major (n_eff, 2, 3) grid, matching the bz genome layout
+    # ----------------------------------------------------- deployment API
+    def prior_logits(self, vec) -> jnp.ndarray:
+        """Posterior logits of the flat GNN params ``vec`` over this
+        driver's Boltzmann node grid, the bucket-major (n_eff, 2, 3)
+        grid matching the bz genome layout — the placement service
+        blends these with neighbor-mapping one-hots before passing the
+        result back through ``warm_start(logits=...)``."""
+        vec = jnp.asarray(vec, jnp.float32)
         return jnp.concatenate(
             [f(vec[None]).reshape(1, -1, 2, 3)
              for f in self._bucket_logits], axis=1)[0]
 
+    def warm_start(self, vec, *, gnn_frac: float = 0.5,
+                   noise_std: float = 0.05, t_init: float = 0.5,
+                   logits=None):
+        """Seed the population from a trained policy's flat GNN params
+        (zero-shot warm start — how the placement service turns its
+        accumulated prior into a head start for each miss batch's
+        refinement).  GNN row 0 becomes the prior EXACTLY (so one elite
+        generation preserves it verbatim), the next ``gnn_frac`` of the
+        sub-population noisy copies, the rest keep their random init
+        for diversity; EVERY Boltzmann genome is re-seeded from the
+        prior's posterior logits (Algorithm 2's GNN->Boltzmann seeding,
+        applied at init time via ``bz.seed_from_logits``).  Draws from
+        the driver's key stream, so warm-started trajectories are
+        deterministic per (cfg.seed, call order); padded sharding rows
+        stay untouched and the result is re-placed in the population
+        sharding.
+
+        ``logits`` (optional, the driver's (n_eff, 2, 3) Boltzmann node
+        grid — see ``prior_logits``) overrides the prior's posterior
+        logits for the Boltzmann re-seeding: the placement service passes a
+        blend of the GNN prior's logits and one-hot logits derived from
+        a nearest-neighbor's committed MAPPING, so a near-identical
+        graph's refinement starts from its neighbor's answer instead of
+        the prior alone.  The GNN rows still seed from ``vec``."""
+        vec = jnp.asarray(vec, jnp.float32)
+        if self.n_g:
+            n_seed = max(1, int(round(gnn_frac * self.n_g)))
+            rows = [vec] + [
+                vec + noise_std * jax.random.normal(self._k(), vec.shape)
+                for _ in range(n_seed - 1)]
+            self.gnn_pop = self.pop_sharding.put(jnp.concatenate(
+                [jnp.stack(rows), self.gnn_pop[n_seed:]]))
+        if self.n_b:
+            logits = (self.prior_logits(vec) if logits is None
+                      else jnp.asarray(logits, jnp.float32))
+            seeds = [bz.seed_from_logits(logits, self._k(), t_init)
+                     for _ in range(self.n_b)]
+            rows = [bz.to_flat(b.prior, b.log_t) for b in seeds]
+            self.bz_pop = self.pop_sharding.put(jnp.concatenate(
+                [jnp.stack(rows), self.bz_pop[self.n_b:]]))
+
     def best_gnn_vec(self) -> Optional[np.ndarray]:
         """Flat params of the best GNN after a generation (row 0); usable
-        directly by the per-graph ``EGRL`` / ``evaluate_gnn_on`` and the
-        batched ``evaluate_gnn_zoo``.  Falls back to the ZooSAC actor
-        when there is no GNN sub-population ("pg" ablation)."""
+        directly by ``evaluate_gnn_zoo`` and ``warm_start``.  Falls back
+        to the ZooSAC actor when there is no GNN sub-population ("pg"
+        ablation)."""
         if self.n_g:
             return np.asarray(self.gnn_pop[0])
         if self.learner is not None:
             return np.asarray(gnn.flatten_params(self.learner.actor))
         return None
-
-
-def evaluate_gnn_on(graph: WorkloadGraph, vec: np.ndarray,
-                    n_features: int = None, samples: int = 8, seed: int = 0):
-    """Zero-shot transfer (Fig 5): apply a trained GNN policy to another
-    workload, report the best speedup over `samples` stochastic rollouts."""
-    feats = jnp.asarray(graph.features())
-    adj = jnp.asarray(graph.adjacency())
-    template = gnn.init_gnn(jax.random.PRNGKey(0), feats.shape[1])
-    params = gnn.unflatten_params(template, jnp.asarray(vec))
-    logits = gnn.gnn_forward(params, feats, adj)
-    keys = jax.random.split(jax.random.PRNGKey(seed), samples)
-    acts = jax.vmap(lambda k: gnn.sample_actions(k, logits))(keys)
-    acts = jnp.concatenate([acts, gnn.greedy_actions(logits)[None]], 0)
-    sg = build_sim_graph(graph)
-    _, ref = compiler_reference(graph)
-    res = evaluate_population(sg, acts, jnp.float32(ref))
-    return float(np.max(np.asarray(res["speedup"])))
 
 
 def evaluate_gnn_zoo(graphs: Sequence[WorkloadGraph], vec: np.ndarray,

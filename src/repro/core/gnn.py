@@ -7,8 +7,8 @@ The adjacency is dense (graphs are <=1k nodes), symmetrized + self-loops.
 gPool keeps the top-k nodes by a learned score (static k per level), and
 gUnpool scatters back with skip connections — the U-shape of the paper's
 policy. All functions are shape-static per workload, so population forward
-passes vmap over stacked parameter pytrees (one device call per
-generation, see core/egrl.py).
+passes vmap over stacked parameter pytrees (one device call per size
+bucket and generation, see core/egrl.py).
 
 GAT backends: the attention+aggregate inner op of ``_gat`` has three
 implementations selected by the ``backend`` argument (default: the
@@ -227,9 +227,11 @@ def gnn_forward_zoo(p, feats, adj, node_mask, n_nodes, backend=None):
 def population_logits_zoo(template, feats, adj, node_mask, n_nodes,
                           pop_matrix, backend=None):
     """Zoo-wide stacked-population forward: (P, V) flat params ->
-    (P, G, N_max, 2, 3).  Like ``population_logits``, the leading axis
-    is a pure vmap, so a ``("pop",)``-sharded ``pop_matrix`` runs shard
-    by shard; the graph axis is replicated."""
+    (P, G, N_max, 2, 3).  The leading axis is a pure vmap, so a
+    ``("pop",)``-sharded ``pop_matrix`` runs shard by shard
+    (``PopSharding.map_rows``): each device runs the forward only for
+    the genome rows it owns, with no collectives; the graph axis and
+    the ``template`` pytree are replicated."""
     return jax.vmap(lambda vec: gnn_forward_zoo(
         unflatten_params(template, vec), feats, adj, node_mask, n_nodes,
         backend))(pop_matrix)
@@ -270,38 +272,16 @@ def log_prob(logits, actions):
     return jnp.take_along_axis(lp, actions[..., None], axis=-1)[..., 0].sum()
 
 
-def entropy(logits):
-    """Mean per-node entropy (Appendix D averages over nodes)."""
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    return -(jnp.exp(lp) * lp).sum(-1).mean()
-
-
 def entropy_masked(logits, node_mask):
-    """``entropy`` over the REAL rows of one padded graph: logits
-    (N_max, 2, 3), node_mask (N_max,) 1.0 = real.  Padding rows are
-    excluded from both the sum and the divisor, so a no-padding mask
-    reduces this to ``entropy`` exactly — the G=1 parity the zoo SAC
-    learner relies on (core/sac.py)."""
+    """Mean per-node entropy (Appendix D averages over nodes) over the
+    REAL rows of one padded graph: logits (N_max, 2, 3), node_mask
+    (N_max,) 1.0 = real.  Padding rows are excluded from both the sum
+    and the divisor, so with no padding this is the plain mean."""
     lp = jax.nn.log_softmax(logits, axis=-1)
     ent = -(jnp.exp(lp) * lp).sum(-1)                  # (N_max, N_SUB)
     live = node_mask.astype(ent.dtype)
     return (ent * live[:, None]).sum() / jnp.maximum(
         live.sum() * ent.shape[-1], 1.0)
-
-
-def population_logits(template, feats, adj, pop_matrix,
-                      backend: Optional[str] = None):
-    """Stacked-population forward: (P, V) flat params -> (P, N, 2, 3).
-
-    A pure vmap over the leading axis, so a ``("pop",)``-sharded
-    ``pop_matrix`` runs shard by shard (``PopSharding.map_rows``): each
-    device runs the forward only for the genome rows it owns — no host
-    round-trips and no collectives, since per-genome forwards are
-    independent.  ``feats`` / ``adj`` / the ``template`` pytree are
-    replicated.
-    """
-    return jax.vmap(lambda vec: gnn_forward(
-        unflatten_params(template, vec), feats, adj, backend))(pop_matrix)
 
 
 # ------------------------------------------------------- flat param helpers

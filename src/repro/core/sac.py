@@ -11,25 +11,21 @@ space per Appendix D:
   target networks are required — noted deviation from the generic
   pseudocode, exact for this MDP.
 
-Two learners share the same losses and the same one-jitted-scan update
-(``_make_update_scan``):
-
-- ``SACLearner`` — the per-graph policy-gradient member of ``EGRL``,
-  unchanged single-graph forms;
-- ``ZooSAC`` — the multi-workload member of ``ZooEGRL``: actor and
-  double-Q critic run over a size-bucketed zoo (``BucketedZoo``, PR 5) —
-  per gradient step, each bucket contributes a ``(G_k, B)`` replay batch
-  evaluated at ITS OWN padded width.  Since the fused GAT op gained its
-  ``custom_vjp`` pair, both learners train on the default GAT backend —
-  no loss function materializes a dense ``(N, N, H)`` attention tensor
-  (the attention transient is ``(N_max_k, C, H)`` per neighbor chunk on
-  the chunked backend).  The scan's per-step inputs are pytrees (one array per
-  bucket); losses are the per-graph SACLearner losses averaged over the
-  whole zoo, so a one-graph batch reduces to ``SACLearner`` exactly (to
-  ~1e-6, see tests/test_zoo_egrl.py) — the single-graph learner is the
-  G=1 case, and a single-bucket zoo consumes its PRNG keys unchanged
-  (``bucket_keys``), keeping those trajectories bit-identical to the
-  flat ``GraphBatch`` path.
+``ZooSAC`` is the policy-gradient member of ``ZooEGRL``: actor and
+double-Q critic run over a size-bucketed zoo (``BucketedZoo``, PR 5) —
+per gradient step, each bucket contributes a ``(G_k, B)`` replay batch
+evaluated at ITS OWN padded width, and all steps of a generation run in
+one jitted scan (``_make_update_scan``).  Since the fused GAT op gained
+its ``custom_vjp`` pair, the learner trains on the default GAT backend —
+no loss function materializes a dense ``(N, N, H)`` attention tensor
+(the attention transient is ``(N_max_k, C, H)`` per neighbor chunk on
+the chunked backend).  The scan's per-step inputs are pytrees (one array
+per bucket); losses are per-graph losses averaged over the whole zoo, so
+a one-graph zoo trains one graph's policy (tests/test_zoo_egrl.py holds
+its update to a per-graph reference written in the test), and a
+single-bucket zoo consumes its PRNG keys unchanged (``bucket_keys``),
+keeping those trajectories bit-identical to the flat ``GraphBatch``
+path.
 """
 from __future__ import annotations
 
@@ -37,14 +33,12 @@ import dataclasses
 from functools import partial
 from typing import Dict
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
 from repro import obs
 from repro.core import gnn
-from repro.core.replay import ReplayBank, ReplayBuffer
+from repro.core.replay import ReplayBank
 from repro.graphs.batch import GraphBatch
 from repro.graphs.bucketed import BucketedZoo, bucket_keys
 from repro.utils.params import ParamDef, init_params
@@ -85,8 +79,7 @@ def critic_forward_masked(p, feats, adj, node_mask, act_onehot,
     the global pool divides by the REAL node count, so garbage in
     padding slots (replay contents, sampled pad actions, noise) cannot
     reach the Q values.  With no padding every mask op is an identity
-    and sum/count equals the mean pool — ``critic_forward`` (the
-    single-graph learner's form) is exactly this with an all-ones mask.
+    and sum/count equals the mean pool.
 
     Runs under ``jax.grad`` on the DEFAULT GAT backend: every backend is
     differentiable since the fused op gained its ``custom_vjp`` pair, so
@@ -110,15 +103,6 @@ def critic_forward_masked(p, feats, adj, node_mask, act_onehot,
     return q[0], q[1]
 
 
-def critic_forward(p, feats, adj, act_onehot, backend=None):
-    """act_onehot (N,2,3) float -> (q1, q2) scalars: the no-padding
-    (all-real-nodes) case of ``critic_forward_masked`` — one critic
-    implementation to maintain for both learners."""
-    return critic_forward_masked(
-        p, feats, adj, jnp.ones(feats.shape[0], feats.dtype), act_onehot,
-        backend)
-
-
 def _adam_init(params):
     return {"m": jax.tree.map(jnp.zeros_like, params),
             "v": jax.tree.map(jnp.zeros_like, params),
@@ -139,13 +123,12 @@ def _adam_step(lr, params, grads, state):
 
 
 def _make_update_scan(cfg: SACConfig, critic_loss, actor_loss):
-    """All gradient steps of a generation in ONE jitted scan, shared by
-    the single-graph and the zoo learner: per step, one critic Adam step
-    on the noisy one-hot behavioral actions, then one actor Adam step
-    through the updated critic.  ``acts`` / ``rewards`` / ``noise``
-    carry a leading (steps,) axis and may be pytrees (ZooSAC passes one
-    array per size bucket — lax.scan slices every leaf); the loss
-    callables define the per-step batch shape."""
+    """All gradient steps of a generation in ONE jitted scan: per step,
+    one critic Adam step on the noisy one-hot behavioral actions, then
+    one actor Adam step through the updated critic.  ``acts`` /
+    ``rewards`` / ``noise`` carry a leading (steps,) axis and may be
+    pytrees (ZooSAC passes one array per size bucket — lax.scan slices
+    every leaf); the loss callables define the per-step batch shape."""
 
     def update_scan(actor, critic, oa, oc, acts, rewards, noise):
         def step(carry, xs):
@@ -164,88 +147,6 @@ def _make_update_scan(cfg: SACConfig, critic_loss, actor_loss):
         return actor, critic, oa, oc, cl[-1], al[-1], en[-1]
 
     return jax.jit(update_scan)
-
-
-class SACLearner:
-    def __init__(self, feats, adj, key, cfg: SACConfig = SACConfig()):
-        self.cfg = cfg
-        self.feats, self.adj = jnp.asarray(feats), jnp.asarray(adj)
-        k1, k2 = jax.random.split(key)
-        self.actor = gnn.init_gnn(k1, feats.shape[1])
-        self.critic = init_params(critic_defs(feats.shape[1]), k2)
-        self.opt_a = _adam_init(self.actor)
-        self.opt_c = _adam_init(self.critic)
-        self.key = jax.random.PRNGKey(17)
-
-        feats_, adj_ = self.feats, self.adj
-        alpha = cfg.alpha
-
-        def critic_loss(cp, acts_oh, rewards):
-            def one(a):
-                return critic_forward(cp, feats_, adj_, a)
-            q1, q2 = jax.vmap(one)(acts_oh)
-            return jnp.mean((q1 - rewards) ** 2 + (q2 - rewards) ** 2)
-
-        def actor_loss(ap, cp):
-            # default backend: every GAT backend differentiates (custom_vjp)
-            logits = gnn.gnn_forward(ap, feats_, adj_)
-            probs = jax.nn.softmax(logits, axis=-1)
-            q1, q2 = critic_forward(cp, feats_, adj_, probs)
-            ent = gnn.entropy(logits)
-            return -(jnp.minimum(q1, q2) + alpha * ent), ent
-
-        # acts (U, B, N, 2) int32; rewards (U, B); noise (U, B, N, 2, 3)
-        self._update_scan = _make_update_scan(cfg, critic_loss, actor_loss)
-        self._logits = jax.jit(lambda ap: gnn.gnn_forward(ap, feats_, adj_))
-        self._sample_batch = jax.jit(
-            lambda ap, ks: jax.vmap(
-                lambda k: gnn.sample_actions(k, gnn.gnn_forward(
-                    ap, feats_, adj_)))(ks))
-
-    def policy_logits(self, params=None):
-        return self._logits(self.actor if params is None else params)
-
-    def explore_action(self):
-        """Single rollout action (host copy); see explore_actions."""
-        return np.asarray(self.explore_actions(1)[0])
-
-    def explore_actions(self, n: int) -> jnp.ndarray:
-        """(n, N, 2) rollout actions as ONE jitted device call (the
-        forward pass is shared; only the sampling keys differ)."""
-        self.key, k = jax.random.split(self.key)
-        return self._sample_batch(self.actor, jax.random.split(k, n))
-
-    def update(self, buffer: ReplayBuffer, steps: int) -> Dict[str, float]:
-        cfg = self.cfg
-        if len(buffer) < cfg.batch or steps <= 0:
-            return {}
-        # the update already ends on host floats (existing syncs, the
-        # device_read spans of sac.read), so the spans add timing
-        # without any new device wait
-        with obs.span("sac_update", learner="sac", steps=steps,
-                      batch=cfg.batch) as sp:
-            with obs.span("replay.sample"):
-                pairs = [buffer.sample(cfg.batch) for _ in range(steps)]
-                acts = np.stack([p[0] for p in pairs])
-                rews = np.stack([p[1] for p in pairs])
-            with obs.span("sac.upload"):
-                self.key, k = jax.random.split(self.key)
-                noise = jnp.clip(
-                    cfg.action_noise * jax.random.normal(
-                        k, (steps, cfg.batch) + acts.shape[2:] + (3,)),
-                    -cfg.noise_clip, cfg.noise_clip)
-                acts, rews = jnp.asarray(acts), jnp.asarray(rews)
-            with obs.span("sac.scan"):
-                (self.actor, self.critic, self.opt_a, self.opt_c,
-                 cl, al, en) = self._update_scan(
-                    self.actor, self.critic, self.opt_a, self.opt_c,
-                    acts, rews, noise)
-            with obs.span("sac.read"):
-                out = {"critic_loss": obs.device_read(float, cl),
-                       "actor_loss": obs.device_read(float, al),
-                       "entropy": obs.device_read(float, en)}
-            sp.set(**out)
-            return out
 
 
 @partial(jax.jit, static_argnames=("n",))
@@ -283,17 +184,16 @@ class ZooSAC:
     in one device call AND the dense ``(N, N)`` attention work shrinks
     from zoo-wide ``N_max`` to bucket size.
 
-    Losses are the per-graph ``SACLearner`` losses averaged over the
-    whole zoo (equal weight per workload; per-graph terms are
-    concatenated bucket-major before the mean, which for a
-    single-bucket zoo is exactly the flat path's graph order).  On a
-    one-graph batch the PRNG streams (init split, PRNGKey(17)
-    noise/sampling chain via ``bucket_keys`` — a K==1 zoo consumes keys
-    UNCHANGED) and the replay draw order coincide with ``SACLearner``'s,
-    so losses and updated parameters match to ~1e-6 — enforced by
-    tests/test_zoo_egrl.py.  Critic parameters are graph-size
-    independent (shared GAT weights + masked mean pool), exactly like
-    the actor's.
+    Losses are per-graph losses averaged over the whole zoo (equal
+    weight per workload; per-graph terms are concatenated bucket-major
+    before the mean, which for a single-bucket zoo is exactly the flat
+    path's graph order): the critic's squared Bellman error on the
+    noisy one-hot behavioral actions, and the actor's ``-(min(q1, q2) +
+    alpha * entropy)`` on its softmax probabilities.  A one-graph zoo
+    is the single-graph learner (its update matches a per-graph
+    reference to ~1e-6, tests/test_zoo_egrl.py).  Critic parameters are
+    graph-size independent (shared GAT weights + masked mean pool),
+    exactly like the actor's.
     """
 
     def __init__(self, zoo, key, cfg: SACConfig = SACConfig()):
@@ -351,16 +251,7 @@ class ZooSAC:
         # acts: per-bucket (U, G_k, B, N_max_k, 2); rewards (U, G_k, B);
         # noise adds (3,) — all tuples, scanned leaf-wise
         self._update_scan = _make_update_scan(cfg, critic_loss, actor_loss)
-        self._logits = jax.jit(lambda ap: tuple(
-            gnn.gnn_forward_zoo(ap, fe, ad, li, nr)
-            for fe, ad, li, nr in buckets))
-
         self._buckets = buckets
-
-    def policy_logits(self, params=None):
-        """Per-bucket (G_k, N_max_k, 2, 3) zoo logits tuple (padding
-        rows forced to 0)."""
-        return self._logits(self.actor if params is None else params)
 
     def explore_actions(self, n: int):
         """Per-bucket (n, G_k, N_max_k, 2) rollout-action tuple as ONE
@@ -378,8 +269,8 @@ class ZooSAC:
         cfg = self.cfg
         if len(bank) < cfg.batch or steps <= 0:
             return {}
-        # same spans as SACLearner.update; the loss reads of sac.read
-        # are the existing host syncs, so the spans add no device wait
+        # the loss reads of sac.read are the update's existing host
+        # syncs, so the spans add no device wait
         with obs.span("sac_update", learner="zoo_sac", steps=steps,
                       batch=cfg.batch) as sp:
             with obs.span("replay.sample"):

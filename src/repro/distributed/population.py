@@ -24,7 +24,7 @@ single-device run (tests/test_ea_sharding.py).  Padding rows cost only
 their share of redundant evaluation work, never correctness.
 
 Shard-count policy (``REPRO_POP_SHARDS`` env var, or the ``pop_shards``
-argument to ``EGRL``):
+argument to ``ZooEGRL``):
 
 - ``"auto"`` (default): all visible devices, capped at the larger
   sub-population size (a shard with zero real rows in BOTH

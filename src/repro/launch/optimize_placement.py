@@ -15,7 +15,7 @@ import numpy as np
 
 from repro.configs.base import SHAPES
 from repro.configs.registry import ARCH_IDS
-from repro.core.egrl import EGRL, EGRLConfig
+from repro.core.egrl import EGRLConfig, ZooEGRL
 from repro.graphs.extract import extract_for
 from repro.graphs.zoo import PAPER_WORKLOADS
 from repro.launch.compile_cache import enable_compile_cache
@@ -53,11 +53,12 @@ def plan_from_mapping(graph, mapping: np.ndarray, meta: dict) -> dict:
 def optimize(arch: str, shape_name: str, steps: int, mode: str = "egrl",
              seed: int = 0, log=_log.info):
     g = make_graph(arch, shape_name)
-    algo = EGRL(g, EGRLConfig(total_steps=steps, seed=seed), mode=mode)
+    algo = ZooEGRL([g], EGRLConfig(total_steps=steps, seed=seed), mode=mode)
     algo.train(log=log)
+    mapping = algo.best_mapping[0]
     sg = build_sim_graph(g)
-    cmap, clat = compiler_reference(g)
-    res = evaluate(sg, jnp.asarray(algo.best_mapping), jnp.float32(clat))
+    _, clat = compiler_reference(g)
+    res = evaluate(sg, jnp.asarray(mapping), jnp.float32(clat))
     meta = {
         "arch": arch, "shape": shape_name, "graph_nodes": g.n,
         "mode": mode, "env_steps": algo.steps,
@@ -65,7 +66,7 @@ def optimize(arch: str, shape_name: str, steps: int, mode: str = "egrl",
         "latency_ms": float(res["latency"]) * 1e3,
         "compiler_latency_ms": clat * 1e3,
     }
-    return plan_from_mapping(g, algo.best_mapping, meta), algo
+    return plan_from_mapping(g, mapping, meta), algo
 
 
 def main():

@@ -30,7 +30,7 @@ stack into that server:
   request queues like a normal miss, but its refinement warm-starts
   from the neighbor's mapping: the Boltzmann population is re-seeded
   from one-hot mapping logits blended into the GNN prior's posterior
-  (``_EvoPopulation.warm_start(logits=...)``) instead of the prior
+  (``ZooEGRL.warm_start(logits=...)``) instead of the prior
   alone.  Exact-hash semantics are unchanged: the sketch is only
   consulted after an exact-match miss.
 

@@ -111,46 +111,24 @@ print("BITWISE-OK")
     assert "BITWISE-OK" in out
 
 
-def test_egrl_trajectory_matches_across_sharding():
-    """EA-mode generations produce the same rewards/fitness trajectory
-    sharded over 4 devices as on a single device (small pop, fast)."""
-    out = run_py("""
-from repro.core.egrl import EGRL, EGRLConfig
-from repro.graphs.zoo import resnet50
-
-g = resnet50()
-cfg = EGRLConfig(pop_size=16, boltzmann_frac=0.25, elites=4, seed=0)
-trajs = {}
-for shards in (1, 4):
-    algo = EGRL(g, cfg, mode="ea", pop_shards=shards)
-    assert algo.pop_sharding.n_shards == shards
-    trajs[shards] = [(r["gen_best_reward"], r["gen_mean_reward"])
-                     for r in (algo.generation() for _ in range(4))]
-assert trajs[1] == trajs[4], f"{trajs[1]} != {trajs[4]}"
-print("TRAJ-OK")
-""")
-    assert "TRAJ-OK" in out
-
-
 def test_padded_trajectory_matches_unpadded_single_device():
     """PR 3: a population split that does NOT divide the device count is
     padded with masked slots, and the real-row reward trajectory is
     bit-identical to the unpadded single-device run (13/3 padded to
     16/4 over 4 shards)."""
     out = run_py("""
-from repro.core.egrl import EGRL, EGRLConfig
+from repro.core.egrl import ZooEGRL, EGRLConfig
 from repro.graphs.zoo import resnet50
 
-g = resnet50()
 cfg = EGRLConfig(pop_size=16, boltzmann_frac=0.2, elites=4, seed=0)
 trajs = {}
 for shards in ("off", 4):
-    algo = EGRL(g, cfg, mode="ea", pop_shards=shards)
+    algo = ZooEGRL([resnet50()], cfg, mode="ea", pop_shards=shards)
     assert (algo.n_g, algo.n_b) == (13, 3)
     if shards == 4:
         assert (algo.n_g_pad, algo.n_b_pad) == (16, 4)
         assert algo.gnn_pop.shape[0] == 16
-    trajs[shards] = [(r["gen_best_reward"], r["gen_mean_reward"])
+    trajs[shards] = [(r["gen_best_fitness"], r["gen_mean_fitness"])
                      for r in (algo.generation() for _ in range(4))]
 assert trajs["off"] == trajs[4], f'{trajs["off"]} != {trajs[4]}'
 print("PAD-OK")
@@ -158,21 +136,31 @@ print("PAD-OK")
     assert "PAD-OK" in out
 
 
-def test_zoo_egrl_trajectory_matches_across_sharding():
-    """The multi-workload ZooEGRL composes with ("pop",) sharding: the
-    fitness trajectory over a padded 4-shard mesh matches single-device
-    (pop 8 -> 6/2 padded to 8/4)."""
-    out = run_py("""
+@pytest.mark.parametrize("names,pop_size,elites,gens", [
+    # pop 16 -> 12/4, divides 4 shards: no padding
+    (("resnet50",), 16, 4, 4),
+    # pop 8 -> 6/2, padded to 8/4
+    (("resnet50", "resnet101"), 8, 2, 3),
+], ids=["resnet50", "resnet50+resnet101"])
+def test_zoo_egrl_trajectory_matches_across_sharding(names, pop_size, elites,
+                                                     gens):
+    """EA-mode ZooEGRL generations produce the same fitness trajectory
+    sharded over 4 devices as on a single device, for a one-graph zoo
+    and for a two-graph one."""
+    out = run_py(f"""
 from repro.core.egrl import ZooEGRL, EGRLConfig
-from repro.graphs.zoo import resnet50, resnet101
+from repro.graphs.zoo import WORKLOADS
 
-cfg = EGRLConfig(pop_size=8, boltzmann_frac=0.25, elites=2, seed=0)
-trajs = {}
+graphs = [WORKLOADS[n]() for n in {list(names)!r}]
+cfg = EGRLConfig(pop_size={pop_size}, boltzmann_frac=0.25,
+                 elites={elites}, seed=0)
+trajs = {{}}
 for shards in ("off", 4):
-    algo = ZooEGRL([resnet50(), resnet101()], cfg, pop_shards=shards)
+    algo = ZooEGRL(graphs, cfg, mode="ea", pop_shards=shards)
+    assert algo.pop_sharding.n_shards == (1 if shards == "off" else 4)
     trajs[shards] = [(r["gen_best_fitness"], r["gen_mean_fitness"])
-                     for r in (algo.generation() for _ in range(3))]
-assert trajs["off"] == trajs[4], f'{trajs["off"]} != {trajs[4]}'
+                     for r in (algo.generation() for _ in range({gens}))]
+assert trajs["off"] == trajs[4], f'{{trajs["off"]}} != {{trajs[4]}}'
 print("ZOO-SHARD-OK")
 """)
     assert "ZOO-SHARD-OK" in out
@@ -183,17 +171,17 @@ def test_pop64_elite_fitness_trajectory_matches():
     """Acceptance: a pop-64 EA run sharded over a 4-device mesh yields
     the same elite fitness trajectory as the single-device run."""
     out = run_py("""
-from repro.core.egrl import EGRL, EGRLConfig
+from repro.core.egrl import ZooEGRL, EGRLConfig
 from repro.graphs.zoo import resnet50
 
-g = resnet50()
 cfg = EGRLConfig(pop_size=64, boltzmann_frac=0.25, elites=8, seed=0)
 trajs = {}
 for shards in (1, 4):
-    algo = EGRL(g, cfg, mode="ea", pop_shards=shards)
+    algo = ZooEGRL([resnet50()], cfg, mode="ea", pop_shards=shards)
     assert (algo.n_g, algo.n_b) == (48, 16)
     assert algo.pop_sharding.n_shards == shards
-    trajs[shards] = [(r["gen_best_reward"], r["best_reward"])
+    trajs[shards] = [(r["gen_best_fitness"],
+                      r["best_reward_per_graph"]["resnet50"])
                      for r in (algo.generation() for _ in range(3))]
 assert trajs[1] == trajs[4], f"{trajs[1]} != {trajs[4]}"
 print("POP64-OK")

@@ -8,7 +8,7 @@ import pytest
 from repro.core import boltzmann as bz
 from repro.core import ea as ea_mod
 from repro.core import gnn
-from repro.core.egrl import EGRL, EGRLConfig, evaluate_gnn_on
+from repro.core.egrl import EGRLConfig, ZooEGRL, evaluate_gnn_zoo
 from repro.graphs.zoo import resnet50
 
 
@@ -121,7 +121,8 @@ def test_boltzmann_children_seeded_from_gnn_elite_posterior():
 
 def test_egrl_population_is_device_resident():
     g = resnet50()
-    algo = EGRL(g, EGRLConfig(total_steps=21, pop_size=4, elites=1))
+    algo = ZooEGRL([g], EGRLConfig(total_steps=21, pop_size=4, elites=1),
+                   mode="egrl")
     assert algo.gnn_pop.shape[0] == algo.n_g
     assert algo.bz_pop.shape == (algo.n_b, bz.flat_size(g.n))
     algo.generation()
@@ -132,17 +133,18 @@ def test_egrl_population_is_device_resident():
 @pytest.mark.slow
 def test_egrl_improves_over_random_and_learns_validity():
     g = resnet50()
-    algo = EGRL(g, EGRLConfig(total_steps=200, seed=0), mode="egrl")
+    algo = ZooEGRL([g], EGRLConfig(total_steps=200, seed=0), mode="egrl")
     algo.train()
-    assert algo.best_reward > 0  # found valid maps
-    assert algo.history[-1]["best_speedup"] > 0.9  # near/above compiler
-    assert len(algo.buffer) == algo.steps  # every rollout hits the buffer
+    assert algo.best_reward[0] > 0  # found valid maps
+    best = algo.history[-1]["best_reward_per_graph"][g.name]
+    assert best / algo.cfg.reward_scale > 0.9  # near/above compiler
+    assert len(algo.bank) == algo.steps  # every rollout hits the bank
 
 
 def test_ea_only_and_pg_only_run():
     g = resnet50()
     for mode in ("ea", "pg"):
-        algo = EGRL(g, EGRLConfig(total_steps=45, seed=1), mode=mode)
+        algo = ZooEGRL([g], EGRLConfig(total_steps=45, seed=1), mode=mode)
         algo.train()
         assert algo.steps >= 45
 
@@ -150,8 +152,8 @@ def test_ea_only_and_pg_only_run():
 @pytest.mark.slow
 def test_zero_shot_transfer_api():
     g = resnet50()
-    algo = EGRL(g, EGRLConfig(total_steps=63, seed=0))
+    algo = ZooEGRL([g], EGRLConfig(total_steps=63, seed=0), mode="egrl")
     algo.train()
     vec = algo.best_gnn_vec()
-    sp = evaluate_gnn_on(resnet50(), vec, samples=2)
+    sp = evaluate_gnn_zoo([resnet50()], vec, samples=2)[g.name]
     assert sp >= 0.0

@@ -87,7 +87,7 @@ def test_bank_routes_rows_per_graph_and_stacks_samples():
 
 def test_bank_single_graph_matches_buffer_draw_order():
     """The G=1 bank must reproduce a plain ReplayBuffer's sample stream
-    — the contract the ZooSAC<->SACLearner parity relies on."""
+    — a one-graph zoo draws its replay as one graph's buffer does."""
     acts, rews = _rows(10)
     buf = ReplayBuffer(n_nodes=3, capacity=32, seed=5)
     buf.add_batch(acts, rews)

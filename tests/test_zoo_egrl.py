@@ -6,10 +6,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import gnn
-from repro.core.egrl import (EGRLConfig, ZooEGRL, evaluate_gnn_on,
-                             evaluate_gnn_zoo)
-from repro.core.replay import ReplayBank, ReplayBuffer
-from repro.core.sac import SACConfig, SACLearner, ZooSAC
+from repro.core.egrl import EGRLConfig, ZooEGRL, evaluate_gnn_zoo
+from repro.core.replay import ReplayBank
+from repro.core.sac import SACConfig, ZooSAC
 from repro.graphs.batch import build_graph_batch
 from repro.graphs.zoo import (EXTRACTED_WORKLOADS, PAPER_WORKLOADS,
                               SMALL_WORKLOADS, SYNTH_WORKLOADS, WORKLOADS,
@@ -121,8 +120,9 @@ def test_zoo_egrl_trains_and_tracks_per_graph_best():
     # best-so-far fitness is monotone
     bests = [r["best_fitness"] for r in recs]
     assert bests == sorted(bests)
-    # a trained zoo GNN drops into the per-graph transfer API
-    sp = evaluate_gnn_on(resnet50(), algo.best_gnn_vec(), samples=2)
+    # a trained zoo GNN drops into the transfer API on one graph
+    sp = evaluate_gnn_zoo([resnet50()], algo.best_gnn_vec(),
+                          samples=2)["resnet50"]
     assert sp >= 0.0
 
 
@@ -171,41 +171,76 @@ def test_zoo_egrl_with_1k_graphs():
 
 # ------------------------------------------------- ZooSAC (the PG member)
 def test_zoo_sac_single_graph_matches_sac_learner():
-    """The zoo learner on a one-graph batch IS the single-graph learner:
-    same init key stream, same replay draw order, same PRNGKey(17) noise
-    chain — losses and updated parameters must agree to ~1e-6 (the zoo
-    losses are per-graph SACLearner losses averaged over G=1; remaining
-    diffs are XLA refusion of the masked identities)."""
+    """The zoo learner on a one-graph batch IS the single-graph learner.
+    The reference is the per-graph loss pair written out here (the
+    unpadded ``gnn.gnn_forward`` actor, the critic with an all-ones
+    mask, the plain mean entropy) run through the same update scan on
+    the same bank draws and the same PRNGKey(17) noise: losses and
+    updated parameters must agree to ~1e-6 (remaining diffs are XLA
+    refusion of the masked identities)."""
+    from repro.core import sac
+    from repro.utils.params import init_params
+
     g = resnet50()
     gb = build_graph_batch([g])
+    assert gb.n_max == g.n                  # one graph: no padding
     key = jax.random.PRNGKey(5)
-    ref = SACLearner(jnp.asarray(g.features()), jnp.asarray(g.adjacency()),
-                     key)
-    zoo = ZooSAC(gb, key)
-    for a, b in zip(jax.tree.leaves(ref.actor), jax.tree.leaves(zoo.actor)):
+    cfg = SACConfig()
+    zoo = ZooSAC(gb, key, cfg)
+    feats, adj = jnp.asarray(g.features()), jnp.asarray(g.adjacency())
+    k1, k2 = jax.random.split(key)
+    actor = gnn.init_gnn(k1, feats.shape[1])
+    critic = init_params(sac.critic_defs(feats.shape[1]), k2)
+    for a, b in zip(jax.tree.leaves(actor), jax.tree.leaves(zoo.actor)):
         assert (a == b).all()                 # identical init
-    for a, b in zip(jax.tree.leaves(ref.critic),
-                    jax.tree.leaves(zoo.critic)):
+    for a, b in zip(jax.tree.leaves(critic), jax.tree.leaves(zoo.critic)):
         assert (a == b).all()
+
+    ones = jnp.ones(g.n, feats.dtype)
+
+    def critic_loss(cp, acts_oh, rewards):
+        q1, q2 = jax.vmap(lambda a: sac.critic_forward_masked(
+            cp, feats, adj, ones, a))(acts_oh)
+        return jnp.mean((q1 - rewards) ** 2 + (q2 - rewards) ** 2)
+
+    def actor_loss(ap, cp):
+        logits = gnn.gnn_forward(ap, feats, adj)
+        probs = jax.nn.softmax(logits, axis=-1)
+        q1, q2 = sac.critic_forward_masked(cp, feats, adj, ones, probs)
+        lp = jax.nn.log_softmax(logits, axis=-1)
+        ent = -(jnp.exp(lp) * lp).sum(-1).mean()
+        return -(jnp.minimum(q1, q2) + cfg.alpha * ent), ent
 
     rng = np.random.default_rng(0)
     acts = rng.integers(0, 3, (40, g.n, 2))
     rews = rng.standard_normal(40).astype(np.float32)
-    buf = ReplayBuffer(g.n, seed=0)
-    buf.add_batch(acts, rews)
-    bank = ReplayBank([gb.n_max], seed=0)
-    bank.add_batch(acts[:, None], rews[:, None])
+    banks = [ReplayBank([gb.n_max], seed=0) for _ in range(2)]
+    for bank in banks:
+        bank.add_batch(acts[:, None], rews[:, None])
 
-    info_ref = ref.update(buf, steps=3)
-    info_zoo = zoo.update(bank, steps=3)
-    assert info_ref and info_zoo
+    steps = 3
+    # the reference's inputs: the twin bank's draws (the stream the
+    # learner's own bank gives) and the learner's first noise draw
+    ra, rr = banks[1].sample_bucket((0,), cfg.batch, steps)
+    _, nk = jax.random.split(jax.random.PRNGKey(17))
+    noise = jnp.clip(cfg.action_noise * jax.random.normal(
+        nk, ra.shape + (3,)), -cfg.noise_clip, cfg.noise_clip)
+    (ref_actor, ref_critic, _, _, cl, al, en) = sac._make_update_scan(
+        cfg, critic_loss, actor_loss)(
+        actor, critic, sac._adam_init(actor), sac._adam_init(critic),
+        jnp.asarray(ra[:, 0]), jnp.asarray(rr[:, 0]), noise[:, 0])
+    info_ref = {"critic_loss": float(cl), "actor_loss": float(al),
+                "entropy": float(en)}
+
+    info_zoo = zoo.update(banks[0], steps=steps)
+    assert info_zoo
     for k in ("critic_loss", "actor_loss", "entropy"):
         np.testing.assert_allclose(info_zoo[k], info_ref[k],
                                    rtol=1e-5, atol=1e-6)
-    for a, b in zip(jax.tree.leaves(ref.actor), jax.tree.leaves(zoo.actor)):
+    for a, b in zip(jax.tree.leaves(ref_actor), jax.tree.leaves(zoo.actor)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=1e-4, atol=2e-5)
-    for a, b in zip(jax.tree.leaves(ref.critic),
+    for a, b in zip(jax.tree.leaves(ref_critic),
                     jax.tree.leaves(zoo.critic)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=1e-4, atol=2e-5)
